@@ -13,9 +13,10 @@ Times, on the paper's generator families:
         layout, jnp block kernels); ``blocked`` is the fixed R_BLK=8
         baseline and ``blocked-auto`` the measured best over the candidate
         table — the plan-build-time autotune record,
-      - the engine pallas backend     (fused multi-payload kernel; interpret
-        mode off TPU, so only a small instance — interpret timings measure
-        correctness plumbing, not TPU performance);
+      - the engine pallas backend     (fused multi-payload kernel; off the
+        TPU it runs in interpret mode, asked for with
+        REPRO_PALLAS_INTERPRET=1, so only a small instance — interpret
+        timings measure correctness plumbing, not TPU performance);
 
   * ONE greedy round (weighted-Luby step + halo exchange) and ONE RnP round
     (rule sweep + exchange + peel) per backend — the solver hot loops that

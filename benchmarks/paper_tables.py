@@ -99,7 +99,7 @@ def bench_solver_quality() -> Iterator[Row]:
             cfg = D.DisReduConfig(heavy_k=8, mode=mode)
             S.solve(pg, algo, cfg)  # compile
             t0 = time.perf_counter()
-            members, _ = S.solve(pg, algo, cfg)
+            members, _, _ = S.solve(pg, algo, cfg)
             us = (time.perf_counter() - t0) * 1e6
             results[f"{tag}{sfx}"] = (g.set_weight(members), us)
     best = max(w for w, _ in results.values())
@@ -126,7 +126,7 @@ def bench_weak_scaling() -> Iterator[Row]:
             state, prob, _ = D.disredu(pg, cfg)
             dt = time.perf_counter() - t0
             nv, _ = D.kernel_stats(pg, state)
-            members, _ = S.solve(pg, "rnp", cfg)
+            members, _, _ = S.solve(pg, "rnp", cfg)
             q = g.set_weight(members)
             yield (
                 f"weak_scaling/{fam}/p{p}", dt * 1e6,
@@ -191,7 +191,7 @@ def bench_kernel_compaction() -> Iterator[Row]:
                            descent_every=2)
     S.solve(part.partition_graph(g, 8, window_cap=16), "rnp", cfg)  # warm
     t0 = _t.perf_counter()
-    m1, _ = S.solve(part.partition_graph(g, 8, window_cap=16), "rnp", cfg)
+    m1, _, _ = S.solve(part.partition_graph(g, 8, window_cap=16), "rnp", cfg)
     t_plain = _t.perf_counter() - t0
     S.solve_staged(g, 8, "rnp", dcfg)  # warm
     t0 = _t.perf_counter()

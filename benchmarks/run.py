@@ -2,7 +2,8 @@
 # ``--engine-only`` (or the default full run) also times one reduction
 # sweep per aggregate backend and writes BENCH_engine.json.
 # ``--serve`` runs the batched-serving throughput bench (BENCH_serve.json);
-# see benchmarks/compare.py for the CI bench-regression gate.
+# see benchmarks/compare.py for the CI bench-regression gate.  On the CPU
+# the pallas rows need REPRO_PALLAS_INTERPRET=1 (interpret mode on request).
 import argparse
 import os
 import sys
@@ -50,6 +51,9 @@ def main() -> None:
         os.path.dirname(__file__), "..", "BENCH_serve.json"))
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.serve:
         from benchmarks.serve_bench import run_serve_bench
 

@@ -15,10 +15,15 @@ fetch ms and the pipeline overlap ratio) from ``MWISService.stats``.
 Batch-4 rows get an ``instances_per_sec_pipelined`` column driven with
 multi-chunk calls (4 chunks per ``solve_batch``) so the overlapped host
 pipeline actually engages.  A ``devices=N`` multi-device section shards
-the batch axis over a ``serve`` mesh — when fewer devices are visible
-than requested the rows run in a subprocess with
+the batch axis over a ``serve`` mesh, in this process when N devices are
+visible.  On the CPU with fewer, the rows run in a child process with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (CPU emulation:
-correctness + overlap surface, not real accelerator speedup).
+correctness + overlap surface, not real accelerator speedup).  On an
+accelerator with fewer the section fails: a child would find the chip held
+by this process.
+
+On the CPU the pallas rows run the kernel in interpret mode, which must be
+asked for with ``REPRO_PALLAS_INTERPRET=1``.
 """
 
 from __future__ import annotations
@@ -103,16 +108,20 @@ def _multidevice_rows(small: bool, devices: int) -> list:
 
 
 def _multidevice_section(small: bool, devices: int = MULTIDEVICE_N) -> list:
-    """Multi-device rows, in-process when enough devices are visible,
-    else via a subprocess with forced CPU host devices.  Returns [] (with
-    a warning) if the subprocess fails — the rest of the bench stands."""
+    """Multi-device rows: in this process when enough devices are
+    visible; on the CPU otherwise in a child with forced host devices.
+    Raises when the section cannot run."""
     import jax
 
     if jax.device_count() >= devices:
         return _multidevice_rows(small, devices)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"multidevice section needs {devices} devices, "
+            f"{jax.device_count()} {jax.default_backend()} device(s) visible")
     out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        ".serve_md_rows.json")
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={devices}"
                         ).strip()
@@ -120,15 +129,11 @@ def _multidevice_section(small: bool, devices: int = MULTIDEVICE_N) -> list:
            "--multidevice-child", out, str(devices)]
     if small:
         cmd.append("--small")
-    try:
-        subprocess.run(cmd, env=env, check=True, timeout=3600)
-        with open(out) as f:
-            rows = json.load(f)
-        os.remove(out)
-        return rows
-    except Exception as e:  # noqa: BLE001 — bench degrades, not dies
-        print(f"# multidevice section skipped: {e}", flush=True)
-        return []
+    subprocess.run(cmd, env=env, check=True, timeout=3600)
+    with open(out) as f:
+        rows = json.load(f)
+    os.remove(out)
+    return rows
 
 
 def run_serve_bench(out_path: str, small: bool = False) -> dict:
@@ -276,6 +281,9 @@ def run_serve_bench(out_path: str, small: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     small = "--small" in sys.argv
     if "--multidevice-child" in sys.argv:
         # child mode: XLA_FLAGS is already in the environment (set by the

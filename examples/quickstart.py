@@ -23,6 +23,6 @@ print(f"DisReduA: {rounds} rounds, kernel |V'|/|V|={nv / g.n:.4f} "
       f"|E'|/|E|={ne / max(g.m, 1):.4f}")
 
 # 4. full reduce-and-peel solver (RnPA) + verification
-members, _ = S.solve(pg, "rnp", D.DisReduConfig(mode="async"))
+members, _, _ = S.solve(pg, "rnp", D.DisReduConfig(mode="async"))
 assert g.is_independent_set(members)
 print(f"RnPA solution: weight={g.set_weight(members)} size={members.sum()}")

@@ -38,7 +38,7 @@ def main():
         for algo in ("greedy", "rg", "rnp"):
             pg2 = part.partition_graph(g, args.p, window_cap=16)
             t0 = time.time()
-            members, _ = S.solve(pg2, algo, D.DisReduConfig(mode="async"))
+            members, _, _ = S.solve(pg2, algo, D.DisReduConfig(mode="async"))
             dt = time.time() - t0
             assert g.is_independent_set(members)
             w = g.set_weight(members)

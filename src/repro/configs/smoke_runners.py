@@ -169,6 +169,6 @@ def mwis_smoke():
 
     g = gen.rgg2d(200, avg_deg=6, seed=0)
     pg = part.partition_graph(g, 4, window_cap=8)
-    members, _ = S.solve(pg, "rnp", DisReduConfig(heavy_k=6, mode="async"))
+    members, _, _ = S.solve(pg, "rnp", DisReduConfig(heavy_k=6, mode="async"))
     assert g.is_independent_set(members)
     assert g.set_weight(members) > 0

@@ -41,21 +41,6 @@ from repro.core.partition import PartitionedGraph
 UNDECIDED, INCLUDED, EXCLUDED, FOLDED = 0, 1, 2, 3
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map across jax versions (new API vs jax.experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 @dataclasses.dataclass(frozen=True)
 class DisReduConfig:
     heavy_k: int = 8
@@ -239,6 +224,16 @@ def shard_map_arrays(pg: PartitionedGraph, cfg: DisReduConfig):
     return arrs
 
 
+def place_on_mesh(arrs: dict, mesh, axis: str = "pe") -> dict:
+    """Put each stacked [p, ...] host array straight onto its PE's device
+    (``NamedSharding(mesh, P(axis))``), never staging it on device 0."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sharding = NamedSharding(mesh, P(axis))
+    return {k: jax.device_put(np.asarray(v), sharding)
+            for k, v in arrs.items()}
+
+
 def _unpack_per_pe(pg: PartitionedGraph, keys, args):
     """Squeeze the leading PE axis and rebuild (aux, halo, plan, a)."""
     a = dict(zip(keys, [x.reshape(x.shape[1:]) for x in args]))
@@ -314,11 +309,12 @@ def disredu_shard_map_fn(pg: PartitionedGraph, cfg: DisReduConfig, mesh,
 
     in_specs = tuple(P(axis) for _ in keys)
     out_specs = (P(axis),) * 8
-    fn = shard_map_compat(per_pe, mesh, in_specs, out_specs)
+    fn = jax.shard_map(per_pe, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     def run(arrays=None):
-        arrays = arrays if arrays is not None else \
-            {k: jnp.asarray(v) for k, v in arrs.items()}
+        if arrays is None:
+            arrays = place_on_mesh(arrs, mesh, axis)
         return fn(*(arrays[k] for k in keys))
 
     return run, keys

@@ -189,20 +189,19 @@ def autotune_r_blk(
     (n_blocks * E_BLK) — the HBM traffic this memory-bound op pays — with
     ties broken toward the smaller R_BLK (cheaper one-hot matmul).
 
-    ``row`` may be stacked [p, E]: the cost then models the stacked
-    packing's SHARED edge budget (max of the per-PE maxima), matching
-    ``pack_blocks_stacked``.
+    ``row`` may be stacked [p, E] (or a list of p row arrays): the cost
+    then models the stacked packing's SHARED edge budget (max of the
+    per-PE maxima), matching ``pack_blocks_stacked``.
     """
-    rows = np.asarray(row)
-    if rows.ndim == 1:
-        rows = rows[None, :]
+    if isinstance(row, np.ndarray) and row.ndim == 1:
+        row = [row]
     best_r, best_cost = candidates[0], None
     for r in candidates:
         n_blocks = max((n_rows + r - 1) // r, 1)
         e_blk = max(
-            int(np.bincount(rows[i] // r, minlength=n_blocks)
+            int(np.bincount(np.asarray(rows_i) // r, minlength=n_blocks)
                 .max(initial=1))
-            for i in range(rows.shape[0])
+            for rows_i in row
         )
         e_blk = ((max(e_blk, 1) + E_BLK_MULTIPLE - 1) // E_BLK_MULTIPLE) \
             * E_BLK_MULTIPLE
@@ -210,6 +209,25 @@ def autotune_r_blk(
         if best_cost is None or cost < best_cost:
             best_r, best_cost = r, cost
     return best_r
+
+
+def plan_edges(row: np.ndarray, gid: np.ndarray) -> np.ndarray:
+    """Ids of the edges a blocked plan packs: every edge of a real row, and
+    one edge of each row with ``gid < 0``.
+
+    Such rows are the partition's nil slots; their edges are the padding
+    of the stacked edge axis (row = col = nil), which ``pack_blocks`` would
+    otherwise crowd into one row block, so the shared edge budget E_BLK —
+    and every [n_blocks, E_BLK] plan array — would grow with the padding.
+    A nil vertex is never active, so its sum payloads are 0; max/min/or
+    over copies of one edge's payload equal one copy.  The reduced rows
+    thus come out exactly as with every padding edge packed.
+    """
+    row = np.asarray(row)
+    pad = np.asarray(gid)[row] < 0
+    pad_ids = np.flatnonzero(pad)
+    _, first = np.unique(row[pad_ids], return_index=True)
+    return np.sort(np.concatenate([np.flatnonzero(~pad), pad_ids[first]]))
 
 
 def _window_payloads(
@@ -257,10 +275,12 @@ def build_plan(
     (col/gid/window/win_adj_bits) additionally packs the act_bits/clique
     payloads so the fused pass can emit the window bits.
     """
+    row = np.asarray(row)
+    edges = None if gid is None else plan_edges(row, gid)
     if r_blk is None:
-        r_blk = autotune_r_blk(np.asarray(row), n_rows)
+        r_blk = autotune_r_blk(row if edges is None else row[edges], n_rows)
     perm, lrow, _ = pack_blocks(
-        np.asarray(row), n_rows, r_blk=r_blk, e_blk_multiple=E_BLK_MULTIPLE
+        row, n_rows, r_blk=r_blk, e_blk_multiple=E_BLK_MULTIPLE, edges=edges
     )
     wbits = wnh = None
     if window is not None:
@@ -284,10 +304,15 @@ def build_plan_stacked(
 
     ``r_blk=None`` autotunes one shared height over all PEs' rows."""
     rows = np.asarray(rows)
+    edges = None if gids is None else [
+        plan_edges(r, g) for r, g in zip(rows, gids)]
     if r_blk is None:
-        r_blk = autotune_r_blk(rows, n_rows)
+        r_blk = autotune_r_blk(
+            rows if edges is None else [r[e] for r, e in zip(rows, edges)],
+            n_rows)
     perm, lrow, _ = pack_blocks_stacked(
-        rows, n_rows, r_blk=r_blk, e_blk_multiple=E_BLK_MULTIPLE
+        rows, n_rows, r_blk=r_blk, e_blk_multiple=E_BLK_MULTIPLE,
+        edges=edges,
     )
     wbits = wnh = None
     if windows is not None:

@@ -56,9 +56,11 @@ Multi-device serving (the throughput lever past one accelerator):
     solve / fetch) and the achieved overlap ratio are recorded in
     ``MWISService.stats``.
 
-Donation: the per-request weight planes are donated to the jitted batched
-solver on accelerator backends (buffer reuse for the hot serving loop);
-on CPU jax cannot donate, so the flag is elided to keep logs clean.
+Donation: the stacked weight plane is donated to the jitted batched
+solver, whose final residual weights reuse its buffer.  A donated plane is
+dead after its launch, so weights live on the host (``Topology.prob.w0``
+is a numpy plane) and every launch — retries and fallbacks included —
+stages a fresh device plane from them.
 
 Robustness (the hardened-serving layer):
 
@@ -183,8 +185,9 @@ class Topology(NamedTuple):
     """Cached per-topology artifact: everything derived from the edge list.
 
     ``prob`` is a p=1 UnionProblem whose w0 is a placeholder — requests
-    refill only the weight plane.  ``n`` is the true (unpadded) vertex
-    count; members/weights are read back as ``members[:n]``.
+    refill only the weight plane, as a host (numpy) array.  ``n`` is the
+    true (unpadded) vertex count; members/weights are read back as
+    ``members[:n]``.
     """
 
     prob: SOL.UnionProblem
@@ -384,21 +387,19 @@ class MWISService:
         cfg = self.cfg
 
         def one(w0, is_local, is_ghost, aux, halo, plan):
-            state, members = SOL.solve_union_arrays(
+            state, members, _ = SOL.solve_union_arrays(
                 w0, is_local, is_ghost, aux, halo, plan,
                 algo=cfg.algo, heavy_k=cfg.heavy_k,
                 use_heavy=cfg.use_heavy, sweeps=1_000_000,
                 max_rounds=cfg.max_rounds, p=1, schedule=sched,
                 backend=backend,
             )
-            return members, state.offset
+            # the residual weights take over the donated weight plane
+            return members, state.w
 
         plan_axes = None if backend == "jnp" else 0
         batched = jax.vmap(one, in_axes=(0, 0, 0, 0, 0, plan_axes))
-        # donate the per-request weight plane on accelerators; CPU jax
-        # cannot honor donation and would warn on every call
-        donate = () if jax.default_backend() == "cpu" else (0,)
-        fn = jax.jit(batched, donate_argnums=donate)
+        fn = jax.jit(batched, donate_argnums=(0,))
         self._batched_fns[key] = fn
         self.compiles += 1
         return fn
@@ -474,9 +475,7 @@ class MWISService:
                 # a raising pack stays OUT of the cache (get_or_build)
                 topo = self._topology(g, cell, backend)
                 topos.append(Topology(
-                    prob=topo.prob._replace(
-                        w0=jnp.asarray(_weight_plane(g, cell))
-                    ),
+                    prob=topo.prob._replace(w0=_weight_plane(g, cell)),
                     n=topo.n,
                 ))
                 good.append(i)
@@ -493,7 +492,9 @@ class MWISService:
         """Stack a chunk to its static batch size and place it: the batch
         axis is padded to a device-count multiple with phantom repeat-last
         instances (results sliced off on fetch) and device_put with a
-        ``serve``-mesh NamedSharding when more than one device is active."""
+        ``serve``-mesh NamedSharding when more than one device is active.
+        The weight plane is stacked from the host planes into a fresh
+        device array, the one the launch donates."""
         t0 = time.perf_counter()
         k = len(topos)
         bt = self._batch_size(k, cell)
@@ -503,7 +504,7 @@ class MWISService:
             return jax.tree.map(lambda *xs: jnp.stack(xs), *leaves)
 
         probs = [t.prob for t in batch]
-        w0s = stack([p.w0 for p in probs])
+        w0s = jnp.asarray(np.stack([p.w0 for p in probs]))
         is_local = stack([p.is_local for p in probs])
         is_ghost = stack([p.is_ghost for p in probs])
         auxs = stack([p.aux for p in probs])

@@ -29,7 +29,7 @@ from repro.core import exchange as X
 from repro.core import rules as R
 from repro.core.distributed import (
     DisReduConfig, UnionProblem, _unpack_per_pe, build_union_problem,
-    shard_map_arrays, shard_map_compat,
+    place_on_mesh, shard_map_arrays,
 )
 from repro.core.local_reduce import local_reduce
 from repro.core.partition import PartitionedGraph
@@ -104,7 +104,8 @@ def greedy_step(state, aux, *, backend: str = "jnp", plan=None):
 
 def _greedy_rounds(state, aux, ctx: Ctx, max_rounds: int = 100_000,
                    *, backend: str = "jnp", plan=None):
-    """Weighted-Luby rounds until no vertex is UNDECIDED anywhere."""
+    """Weighted-Luby rounds until no vertex is UNDECIDED anywhere;
+    returns (state, rounds)."""
 
     def body(carry):
         state, rounds, _ = carry
@@ -118,10 +119,10 @@ def _greedy_rounds(state, aux, ctx: Ctx, max_rounds: int = 100_000,
         return remaining & (rounds < max_rounds)
 
     remaining0 = ctx.gany((aux.is_local & (state.status == UNDECIDED)).any())
-    state, _, _ = jax.lax.while_loop(
+    state, iters, _ = jax.lax.while_loop(
         cond, body, (state, jnp.zeros((), jnp.int32), remaining0)
     )
-    return state
+    return state, iters
 
 
 def peel_score(state, aux, *, backend: str = "jnp", plan=None):
@@ -140,7 +141,8 @@ def peel_score(state, aux, *, backend: str = "jnp", plan=None):
 
 def _rnp_loop(state, aux, ctx: Ctx, cfg: DisReduConfig,
               max_peels: int = 1_000_000, plan=None):
-    """reduce → peel-one-per-PE → repeat until globally empty (§6)."""
+    """reduce → peel-one-per-PE → repeat until globally empty (§6);
+    returns (state, peel iterations)."""
 
     def body(carry):
         state, it, _ = carry
@@ -155,30 +157,34 @@ def _rnp_loop(state, aux, ctx: Ctx, cfg: DisReduConfig,
         return remaining & (it < max_peels)
 
     remaining0 = ctx.gany((aux.is_local & (state.status == UNDECIDED)).any())
-    state, _, _ = jax.lax.while_loop(
+    state, iters, _ = jax.lax.while_loop(
         cond, body, (state, jnp.zeros((), jnp.int32), remaining0)
     )
-    return state
+    return state, iters
 
 
 def run_algorithm(state, aux, ctx: Ctx, cfg: DisReduConfig, algo: str,
                   plan=None):
-    """algo ∈ {reduce, greedy, rg, rnp} → final state (all local decided for
-    solver algos; kernel remains for 'reduce')."""
+    """algo ∈ {reduce, greedy, rg, rnp} → (final state, iterations).
+
+    All local vertices are decided for the solver algos; the kernel remains
+    for 'reduce'.  Iterations count the algorithm's outer loop: reduce
+    rounds ('reduce'), weighted-Luby rounds ('greedy', and 'rg' after its
+    reduce), peel iterations ('rnp')."""
     if algo == "reduce":
-        state, _ = _reduce_to_fixpoint(state, aux, ctx, cfg, plan=plan)
+        state, iters = _reduce_to_fixpoint(state, aux, ctx, cfg, plan=plan)
     elif algo == "greedy":
-        state = _greedy_rounds(state, aux, ctx, backend=cfg.backend,
-                               plan=plan)
+        state, iters = _greedy_rounds(state, aux, ctx, backend=cfg.backend,
+                                      plan=plan)
     elif algo == "rg":
         state, _ = _reduce_to_fixpoint(state, aux, ctx, cfg, plan=plan)
-        state = _greedy_rounds(state, aux, ctx, backend=cfg.backend,
-                               plan=plan)
+        state, iters = _greedy_rounds(state, aux, ctx, backend=cfg.backend,
+                                      plan=plan)
     elif algo == "rnp":
-        state = _rnp_loop(state, aux, ctx, cfg, plan=plan)
+        state, iters = _rnp_loop(state, aux, ctx, cfg, plan=plan)
     else:
         raise ValueError(f"unknown algo {algo!r}")
-    return state
+    return state, iters
 
 
 # --------------------------------------------------------------------- #
@@ -211,7 +217,8 @@ def _union_ctx(prob: UnionProblem, backend: str = "jnp") -> Ctx:
 def solve_union_arrays(w0, is_local, is_ghost, aux, halo, plan, *, algo,
                        heavy_k, use_heavy, sweeps, max_rounds, p,
                        schedule="cheap", backend="jnp"):
-    """Traceable union-path solve body: arrays in, (state, members) out.
+    """Traceable union-path solve body: arrays in, (state, members,
+    iterations) out (iterations as :func:`run_algorithm` counts them).
 
     This is the batch-axis seam of the serving layer: every argument is a
     plain array pytree (no host-side build), so ``jax.vmap`` over a leading
@@ -228,9 +235,9 @@ def solve_union_arrays(w0, is_local, is_ghost, aux, halo, plan, *, algo,
     )
     ctx = _union_ctx(prob, backend)
     state = R.init_state(w0, is_local, is_ghost)
-    state = run_algorithm(state, aux, ctx, cfg, algo, plan=plan)
+    state, iters = run_algorithm(state, aux, ctx, cfg, algo, plan=plan)
     members = R.reconstruct_members(state, aux)
-    return state, members
+    return state, members, iters
 
 
 _solve_union_jit = functools.partial(
@@ -244,14 +251,15 @@ def solve(
     pg: PartitionedGraph,
     algo: str,
     cfg: DisReduConfig = DisReduConfig(),
-) -> Tuple[np.ndarray, R.RedState]:
-    """Solve MWIS heuristically; returns (global member mask, final state).
+) -> Tuple[np.ndarray, R.RedState, int]:
+    """Solve MWIS heuristically; returns (global member mask, final state,
+    iterations as :func:`run_algorithm` counts them).
 
     algo: 'greedy' (GS/GA), 'rg' (RGS/RGA), 'rnp' (RnPS/RnPA) — the S/A
     flavour is chosen by cfg.mode ('sync'/'async').
     """
     prob = build_union_problem(pg, cfg.backend, cfg.r_blk)
-    state, in_set = _solve_union_jit(
+    state, in_set, iters = _solve_union_jit(
         prob.w0, prob.is_local, prob.is_ghost, prob.aux, prob.halo,
         prob.plan,
         algo=algo, heavy_k=cfg.heavy_k, use_heavy=cfg.use_heavy,
@@ -261,7 +269,7 @@ def solve(
     members = np.zeros(pg.n_global, dtype=bool)
     sel = np.asarray(in_set) & np.asarray(prob.is_local)
     members[np.asarray(prob.aux.gid)[sel]] = True
-    return members, state
+    return members, state, int(iters)
 
 
 # --------------------------------------------------------------------- #
@@ -672,18 +680,20 @@ def solver_shard_map_fn(pg: PartitionedGraph, cfg: DisReduConfig, mesh,
 
         ctx = Ctx(exchange=exch, gany=gany, peel=peel)
         state = R.init_state(a["w0"], a["is_local"], a["is_ghost"])
-        state = run_algorithm(state, aux, ctx, cfg, algo, plan=plan)
+        state, iters = run_algorithm(state, aux, ctx, cfg, algo, plan=plan)
         members = R.reconstruct_members(state, aux)
         ex = lambda t: t.reshape((1,) + t.shape)
         return (ex(state.w), ex(state.status), ex(members),
-                ex(state.offset), ex(state.log_n))
+                ex(state.offset), ex(state.log_n), ex(iters))
 
     in_specs = tuple(P(axis) for _ in keys)
-    out_specs = (P(axis),) * 5
-    fn = shard_map_compat(per_pe, mesh, in_specs, out_specs)
+    out_specs = (P(axis),) * 6
+    fn = jax.shard_map(per_pe, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     def run(arrays=None):
-        arrays = arrays or {k: jnp.asarray(v) for k, v in arrs.items()}
+        if arrays is None:
+            arrays = place_on_mesh(arrs, mesh, axis)
         return fn(*(arrays[k] for k in keys))
 
     return run, keys
@@ -715,8 +725,9 @@ def sweep_probe_shard_map_fn(pg: PartitionedGraph, cfg: DisReduConfig, mesh,
         ex = lambda t: t.reshape((1,) + t.shape)
         return ex(state.w), ex(state.status), ex(state.offset)
 
-    fn = shard_map_compat(
-        per_pe, mesh, tuple(P(axis) for _ in keys), (P(axis),) * 3
+    fn = jax.shard_map(
+        per_pe, mesh=mesh, in_specs=tuple(P(axis) for _ in keys),
+        out_specs=(P(axis),) * 3, check_vma=False,
     )
 
     def run(arrays):

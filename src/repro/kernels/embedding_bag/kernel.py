@@ -5,8 +5,9 @@ out[b] = Σ_k weight[b, k] · table[idx[b, k]]   (sum-mode bag)
 JAX has no native EmbeddingBag; the jnp form is gather → multiply →
 segment-sum, three HBM round-trips of the [B·K, dim] gathered matrix.  The
 kernel fuses them: bags are tiled to [B_BLK, dim] output tiles; the table
-stays in HBM (ANY memory space) and rows are DMA'd on demand with
-``pl.load`` dynamic slices, accumulating in a VMEM tile.  dim = 128 is one
+stays in HBM (ANY memory space) and rows are read on demand through
+dynamic ref slices (``table_ref[pl.ds(row, 1), :]``), accumulating in a
+VMEM tile.  dim = 128 is one
 lane tile — MXU/VPU aligned.
 """
 
@@ -25,9 +26,7 @@ def _bag_kernel(idx_ref, wgt_ref, table_ref, out_ref, *, k_bag: int):
     def body(b, _):
         def inner(j, acc):
             row = idx_ref[0, b, j]
-            vec = pl.load(
-                table_ref, (pl.dslice(row, 1), slice(None))
-            )[0].astype(jnp.float32)
+            vec = table_ref[pl.ds(row, 1), :][0].astype(jnp.float32)
             return acc + vec * wgt_ref[0, b, j].astype(jnp.float32)
 
         acc = jax.lax.fori_loop(

@@ -3,7 +3,7 @@ pallas/jnp dispatch."""
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +11,7 @@ import numpy as np
 
 from repro.kernels import interpret_mode, use_pallas
 from repro.kernels.segment_coo.kernel import (
-    segment_fused_blocked, segment_sum_blocked,
+    segment_fused_planar, segment_sum_blocked,
 )
 from repro.kernels.segment_coo.ref import (
     segment_fused_blocked_ref, segment_sum_blocked_ref,
@@ -20,13 +20,16 @@ from repro.kernels.segment_coo.ref import (
 
 def pack_blocks(
     row: np.ndarray, n_rows: int, *, r_blk: int = 8, e_blk_multiple: int = 1,
+    edges: Optional[np.ndarray] = None,
 ) -> Tuple[np.ndarray, np.ndarray, int]:
     """Host packing: row-sorted edge ids → (edge_perm [n_blocks, E_BLK],
     lrow [n_blocks, E_BLK]).  edge_perm indexes the original edge array;
     padding slots point at edge 0 with lrow = r_blk (ignored) — so the edge
     array must be non-empty (the partitioned graphs always pad E ≥ 1).
-    ``e_blk_multiple`` rounds the edge budget up (sublane alignment)."""
-    order = np.argsort(row, kind="stable")
+    ``e_blk_multiple`` rounds the edge budget up (sublane alignment);
+    ``edges`` (ids into ``row``) packs only those edges (default: all)."""
+    ids = np.arange(row.shape[0]) if edges is None else np.asarray(edges)
+    order = ids[np.argsort(row[ids], kind="stable")]
     rs = row[order]
     n_blocks = (n_rows + r_blk - 1) // r_blk
     blk_of_edge = rs // r_blk
@@ -47,15 +50,17 @@ def pack_blocks(
 
 def pack_blocks_stacked(
     rows: np.ndarray, n_rows: int, *, r_blk: int = 8, e_blk_multiple: int = 1,
+    edges: Optional[Sequence[np.ndarray]] = None,
 ) -> Tuple[np.ndarray, np.ndarray, int]:
     """Stacked packing for the shard_map path: rows is [p, E]; every PE is
     packed against the same n_rows and padded to a SHARED E_BLK (max over
     PEs) so the per-PE plan arrays stack into one [p, n_blocks, E_BLK]
-    mesh-sharded input."""
+    mesh-sharded input.  ``edges[i]`` selects PE i's packed edges."""
     p = rows.shape[0]
     packed = [
         pack_blocks(rows[i], n_rows, r_blk=r_blk,
-                    e_blk_multiple=e_blk_multiple)
+                    e_blk_multiple=e_blk_multiple,
+                    edges=None if edges is None else edges[i])
         for i in range(p)
     ]
     e_blk = max(pb[2] for pb in packed)
@@ -113,28 +118,30 @@ def segment_fused_coo(
     if all(d is None for d in (data_sum, data_max, data_min, data_or)):
         raise ValueError("segment_fused_coo needs at least one payload")
     n_blocks, e_blk = edge_perm.shape
-
-    def gather(data):
-        if data is None:
-            return None
-        return data[edge_perm.reshape(-1)].reshape(
-            n_blocks, e_blk, data.shape[-1]
-        )
-
-    bsum, bmax, bmin, bor = (
-        gather(data_sum), gather(data_max), gather(data_min), gather(data_or)
-    )
+    groups = (data_sum, data_max, data_min, data_or)
     enable = use_pallas() if force_pallas is None else force_pallas
     if enable:
-        outs = segment_fused_blocked(
-            bsum, bmax, bmin, lrow, data_or=bor, or_nbits=or_nbits,
-            r_blk=r_blk, interpret=interpret_mode(),
+        # payload-major gather straight into the kernel's [D, nb, E_BLK]
+        # layout (edges on lanes)
+        outs = segment_fused_planar(
+            *(None if d is None else d.T[:, edge_perm] for d in groups[:3]),
+            lrow, r_blk=r_blk, or_nbits=or_nbits,
+            data_or=None if data_or is None else data_or.T[:, edge_perm],
+            interpret=interpret_mode(),
         )
-    else:
-        outs = segment_fused_blocked_ref(
-            bsum, bmax, bmin, lrow, data_or=bor, or_nbits=or_nbits,
-            r_blk=r_blk,
+        return tuple(
+            o.reshape(o.shape[0], n_blocks * r_blk)[:, :n_rows].T
+            if o is not None else None
+            for o in outs
         )
+    bsum, bmax, bmin, bor = (
+        None if d is None else d[edge_perm.reshape(-1)].reshape(
+            n_blocks, e_blk, d.shape[-1])
+        for d in groups
+    )
+    outs = segment_fused_blocked_ref(
+        bsum, bmax, bmin, lrow, data_or=bor, or_nbits=or_nbits, r_blk=r_blk,
+    )
     return tuple(
         o.reshape(n_blocks * r_blk, -1)[:n_rows] if o is not None else None
         for o in outs

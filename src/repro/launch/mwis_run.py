@@ -3,10 +3,11 @@
     PYTHONPATH=src python -m repro.launch.mwis_run \
         --family rhg --n 20000 --p 8 --algo rnp --mode async
 
-Generates (or loads) an instance, partitions it with halos, runs the chosen
-distributed solver on the union simulation path (single device) or the
-shard_map path (with REPRO_PE_DEVICES host devices), verifies independence
-and reports quality vs the sequential baseline.
+Generates an instance, partitions it over ``--p`` PEs with halos, runs the
+chosen distributed solver on the union simulation path (all PEs stacked on
+one device), verifies independence and reports quality vs the sequential
+baseline.  The one-PE-per-chip shard_map path is
+``solvers.solver_shard_map_fn``; ``chip_smoke.py --chips 4`` drives it.
 """
 
 from __future__ import annotations
@@ -68,12 +69,12 @@ def main() -> None:
         return
 
     t0 = time.time()
-    members, state = S.solve(pg, args.algo, cfg)
+    members, state, iters = S.solve(pg, args.algo, cfg)
     dt = time.time() - t0
     assert g.is_independent_set(members), "solution must be independent!"
     w = g.set_weight(members)
     print(f"{args.algo}/{args.mode}: weight={w} |I|={members.sum()} "
-          f"time={dt:.2f}s")
+          f"iterations={iters} time={dt:.2f}s")
 
     if args.compare_seq:
         from repro.core import sequential as seq
@@ -85,4 +86,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

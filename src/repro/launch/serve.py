@@ -4,7 +4,9 @@ The default ``mwis`` arch drives the batched many-instance front end
 (:mod:`repro.core.serve`): a stream of random instances is bucketed into
 the static serve cells, topology-cached, and solved as vmapped batches;
 the driver reports sustained instances/sec, p50/p99 batch latency, and
-plan-cache statistics.
+plan-cache statistics.  It exits non-zero when any request errored or
+failed verification, or when the configured backend was demoted down the
+fallback chain — a demoted run is not a run of the backend asked for.
 
     PYTHONPATH=src python -m repro.launch.serve --arch mwis --requests 64
     PYTHONPATH=src python -m repro.launch.serve --arch mwis --algo rnp \\
@@ -16,18 +18,44 @@ plan-cache statistics.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 ARCHES = ("mwis", "dlrm-mlperf", "gemma3-1b", "qwen3-32b",
           "mistral-nemo-12b")
 
 
-def _serve_mwis(args) -> None:
-    import jax
+def mwis_requests(cells, n_requests: int, repeat_topologies: int,
+                  seed: int) -> list:
+    """The launcher's instance stream: cycle the serve cells with one GNM
+    topology each (80% of the cell's vertices), each repeated with fresh
+    weights — the production re-auction pattern."""
     import numpy as np
 
-    from repro.core import serve as SV
     from repro.graphs.generators import gnm
+
+    rng = np.random.default_rng(seed)
+    reqs = []
+    topo = 0
+    while len(reqs) < n_requests:
+        cell = cells[topo % len(cells)]
+        n = int(cell.L * 0.8)
+        m = min(2 * n, cell.E // 4)
+        g = gnm(n, m, seed=seed + topo)
+        for _ in range(repeat_topologies):
+            w = rng.integers(1, 201, size=g.n).astype(np.int32)
+            reqs.append(type(g)(indptr=g.indptr, indices=g.indices,
+                                weights=w))
+            if len(reqs) == n_requests:
+                break
+        topo += 1
+    return reqs
+
+
+def _serve_mwis(args) -> None:
+    import jax
+
+    from repro.core import serve as SV
 
     cfg = SV.ServeConfig(algo=args.algo, backend=args.backend,
                          max_batch=args.batch, verify=args.verify,
@@ -36,8 +64,6 @@ def _serve_mwis(args) -> None:
     try:
         svc = SV.MWISService(cfg)
     except ValueError as e:
-        import sys
-
         print(f"error: {e}", file=sys.stderr)
         raise SystemExit(2)
     cells = svc.cells
@@ -50,24 +76,8 @@ def _serve_mwis(args) -> None:
           f"({jax.default_backend()}) "
           f"pipeline={'on' if cfg.pipeline else 'off'}")
 
-    # instance stream: cycle the cells, repeat each topology a few times
-    # (fresh weights each request — the production re-auction pattern)
-    rng = np.random.default_rng(args.seed)
-    reqs = []
-    topo = 0
-    while len(reqs) < args.requests:
-        cell = cells[topo % len(cells)]
-        n = int(cell.L * 0.8)
-        m = min(2 * n, cell.E // 4)
-        g = gnm(n, m, seed=args.seed + topo)
-        for _ in range(args.repeat_topologies):
-            w = rng.integers(1, 201, size=g.n).astype(np.int32)
-            reqs.append(type(g)(indptr=g.indptr, indices=g.indices,
-                                weights=w))
-            if len(reqs) == args.requests:
-                break
-        topo += 1
-
+    reqs = mwis_requests(cells, args.requests, args.repeat_topologies,
+                         args.seed)
     batches = [reqs[i:i + args.batch]
                for i in range(0, len(reqs), args.batch)]
     stats = SV.measure_throughput(svc, batches, warmup=1)
@@ -108,6 +118,13 @@ def _serve_mwis(args) -> None:
           f"pipelined={s['pipelined_chunks']} "
           f"retries={s['pipeline_retries']} "
           f"overlap_ratio={s['overlap_ratio']:.3f}")
+    faults = {k: s[k] for k in ("pack_errors", "solve_errors", "fallbacks",
+                                "verify_failures") if s[k]}
+    if n_err or faults or s["backend_active"] != s["backend"]:
+        print(f"error: {n_err} failed request(s) in the last pass, "
+              f"backend {s['backend']} -> {s['backend_active']}, {faults}",
+              file=sys.stderr)
+        raise SystemExit(1)
 
 
 def main(argv=None) -> None:
@@ -207,4 +224,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -5,3 +5,7 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+# the suite runs the Pallas kernels on the CPU in interpret mode, which the
+# kernels only do when asked (subprocess tests inherit the opt-in)
+os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")

@@ -48,7 +48,7 @@ def test_descent_parity_across_backends_and_algos(algo, backend):
     g = rgg2d(500, avg_deg=8, seed=3)
     cfg0, cfgd = _cfgs(backend)
     pg = part.partition_graph(g, 4, window_cap=12)
-    m_mono, _ = S.solve(pg, algo, cfg0)
+    m_mono, _, _ = S.solve(pg, algo, cfg0)
     m_off, _ = S.solve_staged(g, 4, algo, cfg0, window_cap=12)
     m_on, st = S.solve_staged(g, 4, algo, cfgd, window_cap=12,
                               ladder=TINY_LADDER)

@@ -42,7 +42,7 @@ def test_greedy_equals_sequential_oracle(seed, p):
                     weights=(g.weights % 3 + 1).astype(np.int32))
     want, _ = seq.solve_greedy(g)
     pg = part.partition_graph(g, p, window_cap=8, pad_to=MED_PAD)
-    members, _ = S.solve(pg, "greedy")
+    members, _, _ = S.solve(pg, "greedy")
     assert g.is_independent_set(members)
     assert g.set_weight(members) == want
 
@@ -53,7 +53,7 @@ def test_solvers_complete_and_sound(algo, mode):
     for seed in range(3):
         g = gen.rhg_like(250, avg_deg=6, seed=seed)
         pg = part.partition_graph(g, 4, window_cap=12)
-        members, state = S.solve(
+        members, state, _ = S.solve(
             pg, algo, D.DisReduConfig(heavy_k=6, mode=mode)
         )
         assert g.is_independent_set(members)
@@ -68,7 +68,7 @@ def test_rnp_quality_close_to_sequential():
         g = gen.rhg_like(300, avg_deg=6, seed=seed)
         w_seq, _ = seq.solve_reduce_and_peel(g)
         pg = part.partition_graph(g, 4, window_cap=12)
-        members, _ = S.solve(
+        members, _, _ = S.solve(
             pg, "rnp", D.DisReduConfig(heavy_k=6, mode="async")
         )
         ratios.append(g.set_weight(members) / max(w_seq, 1))

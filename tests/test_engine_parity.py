@@ -200,7 +200,7 @@ def test_solver_paths_identical_across_backends_and_greedy_oracle():
             members = {}
             for backend in E.BACKENDS:
                 pg = part.partition_graph(g, 2, window_cap=8, common_cap=4)
-                m, _ = S.solve(pg, algo, D.DisReduConfig(
+                m, _, _ = S.solve(pg, algo, D.DisReduConfig(
                     heavy_k=6, mode="async", backend=backend
                 ))
                 assert g.is_independent_set(m), f"{name}/{algo}/{backend}"
@@ -217,6 +217,50 @@ def test_solver_paths_identical_across_backends_and_greedy_oracle():
                     err_msg=f"{name}: distributed greedy != sequential "
                             "priority greedy",
                 )
+
+
+@pytest.mark.parametrize("backend", ["blocked", "pallas"])
+def test_plan_packs_one_padding_edge_per_nil_row(backend):
+    """The partition pads every PE's edge list with (nil, nil) edges; the
+    plan packs one per nil row, so its edge budget follows the real edges,
+    and every row — nil rows included — reduces exactly as under jnp."""
+    from repro.kernels.segment_coo.ops import pack_blocks
+
+    g = gen.gnm(300, 1200, seed=7)
+    pg = part.partition_graph(g, 4, window_cap=8, pad_to=dict(E=2000))
+    prob = D.build_union_problem(pg, backend, r_blk=8)
+    row = np.asarray(prob.aux.row)
+    col = np.asarray(prob.aux.col)
+    real = np.asarray(prob.aux.gid)[row] >= 0
+    n = prob.w0.shape[0]
+
+    def e_blk(edges=None):
+        return pack_blocks(row, n, r_blk=8, e_blk_multiple=E.E_BLK_MULTIPLE,
+                           edges=edges)[2]
+
+    got = prob.plan.edge_perm.shape[1]
+    assert got < e_blk()
+    assert got <= e_blk(np.flatnonzero(real)) + E.E_BLK_MULTIPLE
+
+    # payloads as the engine builds them: functions of the endpoints, sums
+    # masked by edge activity (a nil vertex is never active)
+    rng = np.random.default_rng(3)
+    val = jnp.asarray(rng.integers(-1000, 1000, size=n).astype(np.int32))
+    active = jnp.asarray(np.asarray(prob.is_local) | np.asarray(prob.is_ghost))
+    eact = active[row] & active[col]
+    kw = dict(
+        data_sum=jnp.stack([jnp.where(eact, val[col], 0),
+                            eact.astype(jnp.int32)], axis=1),
+        data_max=jnp.stack([val[col], jnp.where(eact, col, -1)], axis=1),
+        data_min=val[row] - val[col],
+        data_or=jnp.where(active[col], val[col] & 0xFF, 0),
+        or_nbits=8,
+    )
+    want = E.aggregate(jnp.asarray(row), n, backend="jnp", **kw)
+    have = E.aggregate(jnp.asarray(row), n, backend=backend, plan=prob.plan,
+                       **kw)
+    for h, w in zip(have, want):
+        np.testing.assert_array_equal(np.asarray(h), np.asarray(w))
 
 
 def test_row_arrays_sorted_for_aggregate_sorted_flag():

@@ -8,7 +8,7 @@ import pytest
 from repro.kernels.embedding_bag.kernel import embedding_bag_fused
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 from repro.kernels.segment_coo.kernel import (
-    segment_fused_blocked, segment_sum_blocked,
+    segment_fused_planar, segment_sum_blocked,
 )
 from repro.kernels.segment_coo.ops import (
     pack_blocks, pack_blocks_stacked, segment_fused_coo, segment_sum_coo,
@@ -75,16 +75,19 @@ def test_segment_fused_kernel_matches_ref_int32(n_rows, n_edges, r_blk):
             edge_perm.shape[0], e_blk, d.shape[-1]
         )
 
-    out_k = segment_fused_blocked(
-        blocked(dsum), blocked(dmax), blocked(dmin), jnp.asarray(lrow),
-        r_blk=r_blk, interpret=True,
+    # the kernel takes payload-major [D, nb, E_BLK] blocks, the reference
+    # edge-major [nb, E_BLK, D]
+    out_k = segment_fused_planar(
+        *(jnp.moveaxis(blocked(d), 2, 0) for d in (dsum, dmax, dmin)),
+        jnp.asarray(lrow), r_blk=r_blk, interpret=True,
     )
     out_r = segment_fused_blocked_ref(
         blocked(dsum), blocked(dmax), blocked(dmin), jnp.asarray(lrow),
         r_blk=r_blk,
     )
-    for k, r in zip(out_k, out_r):
-        np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+    for k, r in zip(out_k[:3], out_r):
+        np.testing.assert_array_equal(np.moveaxis(np.asarray(k), 0, 2),
+                                      np.asarray(r))
     # end-to-end wrapper (pallas-interpret) == canonical jax.ops semantics
     got = segment_fused_coo(
         jnp.asarray(edge_perm), jnp.asarray(lrow), n_rows,
@@ -99,6 +102,37 @@ def test_segment_fused_kernel_matches_ref_int32(n_rows, n_edges, r_blk):
     )
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("case", ["negative", "wrapping"])
+def test_segment_fused_sum_int32_edges(case):
+    """The kernel's limb-split MXU sum is exact over the whole int32 range:
+    negative payloads, and segment sums that wrap past the int32 limits
+    exactly as jax.ops.segment_sum does (folded weights reach them)."""
+    rng = np.random.default_rng(21)
+    n_rows, n_edges, r_blk = 37, 300, 8
+    row = rng.integers(0, n_rows, size=n_edges).astype(np.int32)
+    info = np.iinfo(np.int32)
+    if case == "negative":
+        dsum = rng.integers(info.min, 0, size=(n_edges, 2))
+    else:
+        dsum = rng.choice(
+            [info.max, info.max - 1, info.min, info.min + 1, 1 << 30, -1],
+            size=(n_edges, 2),
+        )
+    dsum = jnp.asarray(dsum.astype(np.int32))
+    dor = jnp.asarray(rng.integers(0, 1 << 16, size=(n_edges, 1)), jnp.int32)
+    edge_perm, lrow, _ = pack_blocks(row, n_rows, r_blk=r_blk)
+    s, _, _, o = segment_fused_coo(
+        jnp.asarray(edge_perm), jnp.asarray(lrow), n_rows,
+        data_sum=dsum, data_or=dor, r_blk=r_blk, force_pallas=True,
+    )
+    want = jax.ops.segment_sum(dsum, jnp.asarray(row), num_segments=n_rows)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(want))
+    want_or = np.zeros((n_rows, 1), np.int32)
+    for e in range(n_edges):
+        want_or[row[e]] |= np.asarray(dor)[e]
+    np.testing.assert_array_equal(np.asarray(o), want_or)
 
 
 def test_segment_fused_partial_payloads_and_ref_dispatch():

@@ -39,7 +39,7 @@ def _oracle(g, algo, backend):
     cfg = DisReduConfig(
         backend=backend, r_blk=None if backend == "jnp" else cell.r_blk
     )
-    members, _ = SOL.solve(pg, algo, cfg)
+    members, _, _ = SOL.solve(pg, algo, cfg)
     return members
 
 
@@ -263,6 +263,27 @@ def test_serve_cli_rejects_unknown_arch(capsys):
     err = capsys.readouterr().err
     for arch in L.ARCHES:
         assert arch in err  # the error lists every valid choice
+
+
+def test_serve_cli_exits_nonzero_on_demoted_backend(monkeypatch, capsys):
+    # a run demoted down the fallback chain is not a run of the backend
+    # asked for: the launcher must fail, not just print the demotion
+    from repro.launch import serve as L
+
+    real = SV.MWISService._execute_chunk
+
+    def execute(self, cell, topos, backend):
+        if backend == "blocked":
+            raise RuntimeError("injected blocked-backend failure")
+        return real(self, cell, topos, backend)
+
+    monkeypatch.setattr(SV.MWISService, "_execute_chunk", execute)
+    with pytest.raises(SystemExit) as e:
+        L.main(["--arch", "mwis", "--backend", "blocked", "--requests", "4",
+                "--batch", "4"])
+    assert e.value.code == 1
+    err = capsys.readouterr().err
+    assert "blocked -> jnp" in err and "fallbacks" in err
 
 
 # --------------------------------------------------------------------- #
@@ -536,6 +557,54 @@ def test_pipeline_dispatch_failure_falls_back_to_sync_path(monkeypatch):
     for got, want in zip(res, ref):
         assert got.ok and np.array_equal(got.members, want.members)
     assert svc.stats["pipeline_retries"] == 1
+
+
+@pytest.mark.parametrize("seam", ["launch", "fetch", "fallback"])
+def test_retry_after_donation_restages_weights(monkeypatch, seam):
+    # the launch donates the stacked weight plane; a chunk that fails
+    # after its launch (pipelined dispatch, in-flight fetch, or the sync
+    # path's backend fallback) must re-stage from the host weights and
+    # never re-run on the donated device array
+    graphs = [gnm(18 + 2 * i, 40, seed=80 + i) for i in range(4)]
+    if seam == "fallback":
+        graphs = graphs[:2]     # one chunk -> the synchronous path
+    backend = "blocked" if seam == "fallback" else "jnp"
+    ref = SV.MWISService(
+        SV.ServeConfig(backend="jnp", max_batch=2, pipeline=False)
+    ).solve_batch(graphs)
+    svc = SV.MWISService(SV.ServeConfig(backend=backend, max_batch=2,
+                                        pipeline=True))
+    donated = []
+    real_launch = SV.MWISService._launch_chunk
+    real_fetch = SV.MWISService._fetch_chunk
+
+    def launch(self, staged):
+        inflight = real_launch(self, staged)
+        inflight.members.block_until_ready()
+        donated.append(staged.args[0])
+        if seam != "fetch" and len(donated) == 1:
+            raise RuntimeError("injected failure after launch")
+        return inflight
+
+    def fetch(self, inflight):
+        if seam == "fetch" and len(donated) == 2 and not fetch.failed:
+            fetch.failed = True
+            raise RuntimeError("injected failure after launch")
+        return real_fetch(self, inflight)
+
+    fetch.failed = False
+    monkeypatch.setattr(SV.MWISService, "_launch_chunk", launch)
+    monkeypatch.setattr(SV.MWISService, "_fetch_chunk", fetch)
+    res = svc.solve_batch(graphs)
+    assert donated and all(w.is_deleted() for w in donated)
+    for got, want in zip(res, ref):
+        assert got.ok and np.array_equal(got.members, want.members)
+    st = svc.stats
+    assert st["solve_errors"] == 0
+    if seam == "fallback":
+        assert st["fallbacks"] == 1 and st["backend_active"] == "jnp"
+    else:
+        assert st["pipeline_retries"] == 1 and st["fallbacks"] == 0
 
 
 def test_descent_auto_takes_staged_single_device_path():

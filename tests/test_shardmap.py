@@ -29,7 +29,7 @@ SCRIPT = textwrap.dedent("""
         run, keys = S.solver_shard_map_fn(pg, cfg, mesh, "rnp", axis="pe")
         import jax.numpy as jnp
         arrays = {k: jnp.asarray(v) for k, v in pg.device_arrays().items()}
-        w, status, members, offset, logn = run(arrays)
+        w, status, members, offset, logn, peels = run(arrays)
         members = np.asarray(members)
         gids = pg.gid
         glob = np.zeros(g.n, dtype=bool)
@@ -39,7 +39,7 @@ SCRIPT = textwrap.dedent("""
         assert g.is_independent_set(glob), exchange
         out[exchange] = int(g.weights[glob].sum())
     # union-path result for comparison
-    members_u, _ = S.solve(pg, "rnp", D.DisReduConfig(heavy_k=6, mode="sync"))
+    members_u, _, _ = S.solve(pg, "rnp", D.DisReduConfig(heavy_k=6, mode="sync"))
     out["union"] = int(g.weights[members_u].sum())
     print("RESULT " + json.dumps(out))
 """)

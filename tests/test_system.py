@@ -60,7 +60,7 @@ def test_all_solvers_produce_valid_solutions_all_modes():
     for algo in ("greedy", "rg", "rnp"):
         for mode in ("sync", "async"):
             pg = part.partition_graph(g, 4, window_cap=12)
-            members, _ = S.solve(
+            members, _, _ = S.solve(
                 pg, algo, D.DisReduConfig(heavy_k=6, mode=mode)
             )
             assert g.is_independent_set(members)
@@ -78,7 +78,7 @@ def test_solution_quality_vs_sequential_baseline():
         g = gen.rgg2d(700, avg_deg=8, seed=seed)
         w_seq, _ = seq.solve_reduce_and_peel(g)
         pg = part.partition_graph(g, 8, window_cap=12)
-        members, _ = S.solve(
+        members, _, _ = S.solve(
             pg, "rnp", D.DisReduConfig(heavy_k=6, mode="async")
         )
         rat.append(g.set_weight(members) / max(w_seq, 1))
@@ -103,7 +103,7 @@ def test_kernel_compaction_driver():
     g = gen.rgg2d(1200, avg_deg=8, seed=4)
     cfg = D.DisReduConfig(mode="async", heavy_k=6)
     pg = part.partition_graph(g, 4, window_cap=12)
-    m_plain, _ = S.solve(pg, "rnp", cfg)
+    m_plain, _, _ = S.solve(pg, "rnp", cfg)
     dcfg = D.DisReduConfig(mode="async", heavy_k=6, descent=True,
                            descent_every=2)
     m_comp, stats = S.solve_staged(g, 4, "rnp", dcfg, window_cap=12)
