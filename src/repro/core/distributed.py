@@ -37,6 +37,7 @@ from repro.core import exchange as X
 from repro.core import rules as R
 from repro.core.local_reduce import local_reduce
 from repro.core.partition import PartitionedGraph
+from repro.core.spans import span
 
 UNDECIDED, INCLUDED, EXCLUDED, FOLDED = 0, 1, 2, 3
 
@@ -77,13 +78,14 @@ class UnionProblem(NamedTuple):
     plan: Optional[E.SegPlan] = None  # blocked-ELL packing (non-jnp backends)
 
 
-def build_union_problem(
+def pack_union_problem(
     pg: PartitionedGraph, backend: str = "jnp",
     r_blk: Optional[int] = None,
     plan_cache: Optional[E.PlanCache] = None,
     plan_tag: Optional[str] = None,
 ) -> UnionProblem:
-    """Stack all PEs into one block-diagonal graph with offset indices.
+    """Stack all PEs into one block-diagonal graph with offset indices, as
+    host (numpy) arrays: :func:`upload_union_problem` places them.
 
     ``plan_cache`` (an :class:`repro.core.engine.PlanCache`) reuses the
     blocked-ELL SegPlan across calls whenever the union topology repeats —
@@ -103,29 +105,55 @@ def build_union_problem(
     window = offset_idx(pg.window).reshape(p * V, -1)
     edge_common = offset_idx(pg.edge_common).reshape(row.shape[0], -1)
     aux = R.Aux(
-        row=jnp.asarray(row), col=jnp.asarray(col),
-        gid=jnp.asarray(pg.gid.reshape(-1)),
-        is_local=jnp.asarray(pg.is_local.reshape(-1)),
-        is_iface=jnp.asarray(pg.is_iface.reshape(-1)),
-        owner_rank=jnp.asarray(pg.owner_pe.reshape(-1)),
-        window=jnp.asarray(window),
-        win_complete=jnp.asarray(pg.win_complete.reshape(-1)),
-        win_adj_bits=jnp.asarray(pg.win_adj_bits.reshape(p * V, -1)),
-        edge_common=jnp.asarray(edge_common),
-    )
-    halo = X.make_halo(pg, pe=None)
-    plan = None if backend == "jnp" else E.plan_for(
-        plan_cache, row, p * V, r_blk=r_blk,
-        col=col, gid=pg.gid.reshape(-1), window=window,
+        row=row, col=col,
+        gid=pg.gid.reshape(-1),
+        is_local=pg.is_local.reshape(-1),
+        is_iface=pg.is_iface.reshape(-1),
+        owner_rank=pg.owner_pe.reshape(-1),
+        window=window,
+        win_complete=pg.win_complete.reshape(-1),
         win_adj_bits=pg.win_adj_bits.reshape(p * V, -1),
-        tag=plan_tag,
+        edge_common=edge_common,
     )
+    plan = None
+    if backend != "jnp":
+        with span("mwis.reduce.plan"):
+            plan = E.plan_for(
+                plan_cache, row, p * V, r_blk=r_blk,
+                col=col, gid=pg.gid.reshape(-1), window=window,
+                win_adj_bits=pg.win_adj_bits.reshape(p * V, -1),
+                tag=plan_tag,
+            )
     return UnionProblem(
-        w0=jnp.asarray(pg.w0.reshape(-1)),
-        is_local=jnp.asarray(pg.is_local.reshape(-1)),
-        is_ghost=jnp.asarray(pg.is_ghost.reshape(-1)),
-        aux=aux, halo=halo, p=p, V=V, plan=plan,
+        w0=pg.w0.reshape(-1),
+        is_local=pg.is_local.reshape(-1),
+        is_ghost=pg.is_ghost.reshape(-1),
+        aux=aux, halo=X.make_halo(pg, pe=None), p=p, V=V, plan=plan,
     )
+
+
+def upload_union_problem(host: UnionProblem) -> UnionProblem:
+    """Place a host-packed union problem's arrays on the device."""
+    put = functools.partial(jax.tree.map, jnp.asarray)
+    return host._replace(
+        w0=jnp.asarray(host.w0), is_local=jnp.asarray(host.is_local),
+        is_ghost=jnp.asarray(host.is_ghost), aux=put(host.aux),
+        halo=put(host.halo), plan=put(host.plan),
+    )
+
+
+def build_union_problem(
+    pg: PartitionedGraph, backend: str = "jnp",
+    r_blk: Optional[int] = None,
+    plan_cache: Optional[E.PlanCache] = None,
+    plan_tag: Optional[str] = None,
+) -> UnionProblem:
+    """:func:`pack_union_problem` on the host, then
+    :func:`upload_union_problem`: the union problem on the device."""
+    with span("mwis.reduce.pack"):
+        host = pack_union_problem(pg, backend, r_blk, plan_cache, plan_tag)
+        with span("mwis.reduce.upload"):
+            return upload_union_problem(host)
 
 
 # --------------------------------------------------------------------- #
@@ -166,7 +194,9 @@ def _disredu_union_jit(
         state, rounds, _ = carry
         snap_s, snap_w = state.status, state.w
         state = _round_union(state, prob, cfg)
-        changed = (state.status != snap_s).any() | (state.w != snap_w).any()
+        with jax.named_scope("mwis.round.vote"):
+            changed = ((state.status != snap_s).any()
+                       | (state.w != snap_w).any())
         return state, rounds + 1, changed
 
     def cond(carry):
@@ -288,10 +318,12 @@ def disredu_shard_map_fn(pg: PartitionedGraph, cfg: DisReduConfig, mesh,
                 state, aux, halo, axis=axis, method=cfg.exchange,
                 backend=cfg.backend, plan=plan,
             )
-            local_changed = (
-                (state.status != snap_s).any() | (state.w != snap_w).any()
-            )
-            changed = jax.lax.psum(local_changed.astype(jnp.int32), axis) > 0
+            with jax.named_scope("mwis.round.vote"):
+                local_changed = (
+                    (state.status != snap_s).any() | (state.w != snap_w).any()
+                )
+                changed = jax.lax.psum(
+                    local_changed.astype(jnp.int32), axis) > 0
             return state, rounds + 1, changed
 
         def cond(carry):

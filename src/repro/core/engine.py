@@ -273,7 +273,9 @@ def build_plan(
     ``r_blk=None`` autotunes the row-block height (see
     :func:`autotune_r_blk`).  Passing the static window structure
     (col/gid/window/win_adj_bits) additionally packs the act_bits/clique
-    payloads so the fused pass can emit the window bits.
+    payloads so the fused pass can emit the window bits.  Packing is host
+    work: the plan's arrays are numpy arrays, placed on the device by the
+    caller (``distributed.build_union_problem``) or at the jitted call.
     """
     row = np.asarray(row)
     edges = None if gid is None else plan_edges(row, gid)
@@ -284,12 +286,11 @@ def build_plan(
     )
     wbits = wnh = None
     if window is not None:
-        wb, wn = _window_payloads(row, col, gid, window, win_adj_bits)
-        wbits, wnh = jnp.asarray(wb), jnp.asarray(wn)
+        wbits, wnh = _window_payloads(row, col, gid, window, win_adj_bits)
     return SegPlan(
-        edge_perm=jnp.asarray(perm, jnp.int32),
-        lrow=jnp.asarray(lrow, jnp.int32),
-        rblk_tpl=jnp.zeros((r_blk, 0), jnp.int32),
+        edge_perm=np.asarray(perm, np.int32),
+        lrow=np.asarray(lrow, np.int32),
+        rblk_tpl=np.zeros((r_blk, 0), np.int32),
         wbits=wbits, wnh=wnh,
     )
 
@@ -476,24 +477,26 @@ def pad_plan(plan: SegPlan, e_blk: int) -> SegPlan:
 
     Padding slots follow the :func:`pack_blocks` convention — edge 0 with
     ``lrow = r_blk`` — which every blocked kernel ignores, so a padded plan
-    is bit-identical in effect to the original.
+    is bit-identical in effect to the original.  Padding is host work: the
+    padded arrays are numpy arrays.
     """
     nb, eb = plan.edge_perm.shape
     if eb > e_blk:
         raise ValueError(f"cannot shrink plan E_BLK {eb} -> {e_blk}")
     if eb == e_blk:
         return plan
-    perm = jnp.zeros((nb, e_blk), jnp.int32).at[:, :eb].set(plan.edge_perm)
-    lrow = jnp.full((nb, e_blk), plan.r_blk, jnp.int32).at[:, :eb].set(
-        plan.lrow
-    )
+    perm = np.zeros((nb, e_blk), np.int32)
+    perm[:, :eb] = np.asarray(plan.edge_perm)
+    lrow = np.full((nb, e_blk), plan.r_blk, np.int32)
+    lrow[:, :eb] = np.asarray(plan.lrow)
     return plan._replace(edge_perm=perm, lrow=lrow)
 
 
 def stack_plans(plans: Sequence[SegPlan],
                 e_blk: Optional[int] = None,
                 batch_multiple: int = 1) -> SegPlan:
-    """Stack same-cell plans onto a leading batch axis (shared E_BLK).
+    """Stack same-cell plans onto a leading batch axis (shared E_BLK), on
+    the host: the stacked arrays are numpy arrays.
 
     All plans must share ``r_blk`` and row count (same serve cell); each is
     padded to the common edge budget — `e_blk` if given (a high-water mark
@@ -528,11 +531,11 @@ def stack_plans(plans: Sequence[SegPlan],
         raise ValueError(f"e_blk={e_blk} below batch requirement {need}")
     padded = [pad_plan(p, e_blk) for p in plans]
     return SegPlan(
-        edge_perm=jnp.stack([p.edge_perm for p in padded]),
-        lrow=jnp.stack([p.lrow for p in padded]),
-        rblk_tpl=jnp.zeros((len(plans), r_blk, 0), jnp.int32),
-        wbits=jnp.stack([p.wbits for p in padded]) if has_w[0] else None,
-        wnh=jnp.stack([p.wnh for p in padded]) if has_w[0] else None,
+        edge_perm=np.stack([p.edge_perm for p in padded]),
+        lrow=np.stack([p.lrow for p in padded]),
+        rblk_tpl=np.zeros((len(plans), r_blk, 0), np.int32),
+        wbits=np.stack([p.wbits for p in padded]) if has_w[0] else None,
+        wnh=np.stack([p.wnh for p in padded]) if has_w[0] else None,
     )
 
 
@@ -651,6 +654,7 @@ def aggregate(
 # --------------------------------------------------------------------- #
 # aggregate computation (SweepCtx for the scheduled rules)
 # --------------------------------------------------------------------- #
+@jax.named_scope("mwis.aggregate")
 def compute_ctx(
     state: R.RedState,
     aux: R.Aux,
@@ -770,11 +774,13 @@ def sweep(
             state, aux, schedule_requires(sched), backend=backend, plan=plan
         )
         for name in sched.rules:
-            state = RULES[name](state, aux, ctx)
+            with jax.named_scope(f"mwis.rule.{name}"):
+                state = RULES[name](state, aux, ctx)
     else:
         for name in sched.rules:
             ctx = compute_ctx(
                 state, aux, RULES[name].requires, backend=backend, plan=plan
             )
-            state = RULES[name](state, aux, ctx)
+            with jax.named_scope(f"mwis.rule.{name}"):
+                state = RULES[name](state, aux, ctx)
     return state

@@ -51,7 +51,8 @@ class Halo(NamedTuple):
 
 
 def make_halo(pg: PartitionedGraph, pe: int | None = None) -> Halo:
-    """pe=None → stacked [p, ...] halo (union layout uses vertex offsets)."""
+    """pe=None → stacked [p, ...] halo as host (numpy) arrays (union layout
+    uses vertex offsets; ``distributed.upload_union_problem`` places it)."""
     import numpy as np
 
     L, G, V = pg.L, pg.G, pg.V
@@ -62,15 +63,15 @@ def make_halo(pg: PartitionedGraph, pe: int | None = None) -> Halo:
         )
         gvert = off + L + np.arange(G)[None, :]
         return Halo(
-            iface_slots=jnp.asarray(iface, jnp.int32),
-            ghost_vertex=jnp.asarray(gvert, jnp.int32),
-            ghost_owner_pe=jnp.asarray(
-                np.maximum(pg.owner_pe[:, L : L + G], 0), jnp.int32
+            iface_slots=np.asarray(iface, np.int32),
+            ghost_vertex=np.asarray(gvert, np.int32),
+            ghost_owner_pe=np.asarray(
+                np.maximum(pg.owner_pe[:, L : L + G], 0), np.int32
             ),
-            ghost_owner_slot=jnp.asarray(pg.ghost_owner_slot, jnp.int32),
-            ghost_valid=jnp.asarray(pg.is_ghost[:, L : L + G]),
-            send_slot=jnp.asarray(pg.send_slot, jnp.int32),
-            recv_ghost=jnp.asarray(pg.recv_ghost, jnp.int32),
+            ghost_owner_slot=np.asarray(pg.ghost_owner_slot, np.int32),
+            ghost_valid=np.asarray(pg.is_ghost[:, L : L + G]),
+            send_slot=np.asarray(pg.send_slot, np.int32),
+            recv_ghost=np.asarray(pg.recv_ghost, np.int32),
         )
     return Halo(
         iface_slots=jnp.asarray(pg.iface_slots[pe], jnp.int32),
@@ -198,6 +199,7 @@ def _board(state: R.RedState, iface_slots: jax.Array) -> Tuple[jax.Array, jax.Ar
     return bw, bs
 
 
+@jax.named_scope("mwis.exchange")
 def exchange_shmap(
     state: R.RedState, aux: R.Aux, halo: Halo, *, axis: str = "pe",
     method: str = "allgather",
@@ -268,6 +270,7 @@ def reconcile_union_boards(
     )
 
 
+@jax.named_scope("mwis.exchange")
 def exchange_union(
     state: R.RedState, aux: R.Aux, halo: Halo, *, p: int,
     backend: str = "jnp", plan: Optional[E.SegPlan] = None,

@@ -60,12 +60,13 @@ def local_reduce(
             state, aux, schedule=schedule, backend=backend, plan=plan
         )
         if use_heavy:
-            state = jax.lax.cond(
-                state.changed,
-                lambda s: s,
-                lambda s: R.rule_heavy_vertex(s, aux, heavy_k),
-                state,
-            )
+            with jax.named_scope("mwis.rule.heavy"):
+                state = jax.lax.cond(
+                    state.changed,
+                    lambda s: s,
+                    lambda s: R.rule_heavy_vertex(s, aux, heavy_k),
+                    state,
+                )
         return state, carry[1] + 1
 
     def cond(carry):

@@ -49,12 +49,13 @@ Multi-device serving (the throughput lever past one accelerator):
   * **overlapped host pipeline** — within one ``solve_batch`` call the
     chunks are double-buffered: while the device solves chunk *k*, the
     host packs, stacks and transfers chunk *k+1* (jax dispatch is async,
-    so the weight refill + ``jnp.stack`` + H2D of the next chunk hide
-    under the in-flight solve instead of serializing with it — the same
-    communication/computation overlap DisReduA uses between PEs, applied
-    to the host→device edge).  Per-stage wall time (pack / transfer /
+    so the weight refill, the host-side stack and the one H2D of the next
+    chunk hide under the in-flight solve instead of serializing with it —
+    the same communication/computation overlap DisReduA uses between PEs,
+    applied to the host→device edge).  Per-stage wall time (pack / transfer /
     solve / fetch) and the achieved overlap ratio are recorded in
-    ``MWISService.stats``.
+    ``MWISService.stats``, from the same intervals as the ``mwis.serve.*``
+    host spans (:mod:`repro.core.spans`).
 
 Donation: the stacked weight plane is donated to the jitted batched
 solver, whose final residual weights reuse its buffer.  A donated plane is
@@ -96,15 +97,16 @@ from collections import deque
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import base as CFG
 from repro.core import engine as E
 from repro.core import solvers as SOL
 from repro.core import validate as V
+from repro.core.distributed import pack_union_problem
 from repro.core.graph import Graph
 from repro.core.partition import partition_graph
+from repro.core.spans import span
 
 #: Backend degradation order: a failing backend falls to the next entry.
 FALLBACK_CHAIN = {
@@ -184,8 +186,8 @@ def bucket_for(n: int, directed_edges: int,
 class Topology(NamedTuple):
     """Cached per-topology artifact: everything derived from the edge list.
 
-    ``prob`` is a p=1 UnionProblem whose w0 is a placeholder — requests
-    refill only the weight plane, as a host (numpy) array.  ``n`` is the
+    ``prob`` is a p=1 UnionProblem of host (numpy) arrays whose w0 is a
+    placeholder — requests refill only the weight plane.  ``n`` is the
     true (unpadded) vertex count; members/weights are read back as
     ``members[:n]``.
     """
@@ -205,7 +207,8 @@ def _pack_topology(g: Graph, cell: ServeCell, backend: str) -> Topology:
             f"(L={pg.L}, E={pg.E}, G={pg.G}) vs cell "
             f"(L={cell.L}, E={cell.E}, G={cell.G})"
         )
-    prob = SOL.build_union_problem(
+    # the cache keeps the host pack: chunks are stacked on the host
+    prob = pack_union_problem(
         pg, backend, None if backend == "jnp" else cell.r_blk
     )
     return Topology(prob=prob, n=g.n)
@@ -257,7 +260,7 @@ class _Inflight(NamedTuple):
 
     staged: _Staged
     members: jax.Array            # async [bt, L+G+1] bool
-    t_dispatch: float
+    solving: span                 # the open mwis.serve.solve span
 
 
 class _Pending(NamedTuple):
@@ -463,48 +466,68 @@ class MWISService:
         graphs: List[Graph],
         out: List[Optional[ServeResult]],
         backend: str,
+        rec: Optional[dict] = None,
     ) -> Tuple[List[Topology], List[int]]:
         """Per-request host packing with fault isolation; failed requests
-        get error results in ``out`` and drop out of the chunk."""
+        get error results in ``out`` and drop out of the chunk.  With
+        ``rec``, its time counts as the chunk's pack stage."""
         topos: List[Topology] = []
         good: List[int] = []
-        for i in idxs:
-            g = graphs[i]
-            try:
-                # per-request weight refill on a cached/fresh topology;
-                # a raising pack stays OUT of the cache (get_or_build)
-                topo = self._topology(g, cell, backend)
-                topos.append(Topology(
-                    prob=topo.prob._replace(w0=_weight_plane(g, cell)),
-                    n=topo.n,
-                ))
-                good.append(i)
-            except Exception as e:  # noqa: BLE001 — isolate the request
-                self.counters["pack_errors"] += 1
-                self.events.append(("pack_error", cell.name, str(e)))
-                out[i] = _error_result(g.n, V.REASON_PACK_FAILED, str(e))
+        with span("mwis.serve.pack", rec, "pack_ms"):
+            for i in idxs:
+                g = graphs[i]
+                try:
+                    # per-request weight refill on a cached/fresh topology;
+                    # a raising pack stays OUT of the cache (get_or_build)
+                    topo = self._topology(g, cell, backend)
+                    topos.append(Topology(
+                        prob=topo.prob._replace(w0=_weight_plane(g, cell)),
+                        n=topo.n,
+                    ))
+                    good.append(i)
+                except Exception as e:  # noqa: BLE001 — isolate the request
+                    self.counters["pack_errors"] += 1
+                    self.events.append(("pack_error", cell.name, str(e)))
+                    out[i] = _error_result(g.n, V.REASON_PACK_FAILED,
+                                           str(e))
         return topos, good
 
     def _stage_chunk(
         self, cell: ServeCell, topos: List[Topology], backend: str,
         rec: dict,
     ) -> "_Staged":
-        """Stack a chunk to its static batch size and place it: the batch
-        axis is padded to a device-count multiple with phantom repeat-last
-        instances (results sliced off on fetch) and device_put with a
-        ``serve``-mesh NamedSharding when more than one device is active.
-        The weight plane is stacked from the host planes into a fresh
-        device array, the one the launch donates."""
-        t0 = time.perf_counter()
+        """Stack a chunk to its static batch size on the host and place it:
+        the batch axis is padded to a device-count multiple with phantom
+        repeat-last instances (results sliced off on fetch) and the chunk
+        goes to the device in one ``device_put``, with a ``serve``-mesh
+        NamedSharding when more than one device is active.  Stacking on the
+        device instead would queue hundreds of small programs behind the
+        chunk in flight and hold the host until that chunk finished.  The
+        placed weight plane is a fresh device array, the one the launch
+        donates."""
+        with span("mwis.serve.pack", rec, "pack_ms"):
+            args, e_blk, bt = self._stack_chunk(cell, topos, backend)
+        nd = self._cell_ndev(cell)
+        with span("mwis.serve.stage", rec, "transfer_ms"):
+            args = jax.device_put(args,
+                                  self._sharding(nd) if nd > 1 else None)
+        rec["batch"] = bt
+        rec["devices"] = nd
+        return _Staged(cell=cell, backend=backend, topos=tuple(topos),
+                       args=args, e_blk=e_blk, rec=rec)
+
+    def _stack_chunk(self, cell, topos, backend):
+        """The chunk's solve arguments as host arrays stacked to its static
+        batch size; returns (args, e_blk, batch size)."""
         k = len(topos)
         bt = self._batch_size(k, cell)
         batch = list(topos) + [topos[-1]] * (bt - k)
 
         def stack(leaves):
-            return jax.tree.map(lambda *xs: jnp.stack(xs), *leaves)
+            return jax.tree.map(lambda *xs: np.stack(xs), *leaves)
 
         probs = [t.prob for t in batch]
-        w0s = jnp.asarray(np.stack([p.w0 for p in probs]))
+        w0s = np.stack([p.w0 for p in probs])
         is_local = stack([p.is_local for p in probs])
         is_ghost = stack([p.is_ghost for p in probs])
         auxs = stack([p.aux for p in probs])
@@ -518,36 +541,34 @@ class MWISService:
             self._eblk_hwm[cell.name] = hwm
             plans = E.stack_plans([p.plan for p in probs], e_blk=hwm)
             e_blk = hwm
-        args = (w0s, is_local, is_ghost, auxs, halos, plans)
-        t1 = time.perf_counter()
-        nd = self._cell_ndev(cell)
-        if nd > 1:
-            args = jax.device_put(args, self._sharding(nd))
-        t2 = time.perf_counter()
-        rec["pack_ms"] += (t1 - t0) * 1e3
-        rec["transfer_ms"] += (t2 - t1) * 1e3
-        rec["batch"] = bt
-        rec["devices"] = nd
-        return _Staged(cell=cell, backend=backend, topos=tuple(topos),
-                       args=args, e_blk=e_blk, rec=rec)
+        return (w0s, is_local, is_ghost, auxs, halos, plans), e_blk, bt
 
     def _launch_chunk(self, staged: "_Staged") -> "_Inflight":
         """Dispatch the jitted vmapped solve; returns without blocking
         (jax dispatch is async — the host is free to pack the next chunk
         while this one runs on the device shards)."""
         fn = self._batched_fn(staged.cell, staged.e_blk, staged.backend)
-        t0 = time.perf_counter()
-        members, _ = fn(*staged.args)
-        return _Inflight(staged=staged, members=members, t_dispatch=t0)
+        # The solve stage is dispatch to ready, across the host work done
+        # meanwhile (the next chunk's pack and stage), so its span opens
+        # here and closes in _fetch_chunk, or in _run_chunks for a chunk
+        # that an escaping error leaves in flight.
+        solving = span("mwis.serve.solve", staged.rec, "solve_ms").open()
+        try:
+            members, _ = fn(*staged.args)
+        except BaseException:
+            solving.close()
+            raise
+        return _Inflight(staged=staged, members=members, solving=solving)
 
     def _fetch_chunk(self, inflight: "_Inflight") -> List[np.ndarray]:
         """Block on the in-flight solve and read back the [n_i] masks."""
         rec = inflight.staged.rec
-        members = inflight.members.block_until_ready()
-        t1 = time.perf_counter()
-        rec["solve_ms"] += (t1 - inflight.t_dispatch) * 1e3
-        members = np.asarray(members)
-        rec["fetch_ms"] += (time.perf_counter() - t1) * 1e3
+        try:
+            members = inflight.members.block_until_ready()
+        finally:
+            inflight.solving.close()
+        with span("mwis.serve.fetch", rec, "fetch_ms"):
+            members = np.asarray(members)
         self._log_stages(rec)
         return [members[i, : t.n]
                 for i, t in enumerate(inflight.staged.topos)]
@@ -622,9 +643,8 @@ class MWISService:
         the chunk through the synchronous fallback-chain path."""
         backend = self._backend
         rec = self._new_rec(cell, backend, pipelined=True)
-        t0 = time.perf_counter()
-        topos, good = self._pack_requests(cell, idxs, graphs, out, backend)
-        rec["pack_ms"] += (time.perf_counter() - t0) * 1e3
+        topos, good = self._pack_requests(cell, idxs, graphs, out, backend,
+                                          rec)
         if not good:
             return None
         try:
@@ -677,19 +697,23 @@ class MWISService:
         t_wall = time.perf_counter()
         pipe = self.cfg.pipeline and len(chunks) > 1
         pending: Optional[_Pending] = None
-        for cell, idxs in chunks:
-            if not (pipe and cell.pipeline):
+        try:
+            for cell, idxs in chunks:
+                if not (pipe and cell.pipeline):
+                    if pending is not None:
+                        self._retire_chunk(pending, graphs, out)
+                        pending = None
+                    self._solve_chunk(cell, idxs, graphs, out)
+                    continue
+                nxt = self._dispatch_chunk(cell, idxs, graphs, out)
                 if pending is not None:
                     self._retire_chunk(pending, graphs, out)
-                    pending = None
-                self._solve_chunk(cell, idxs, graphs, out)
-                continue
-            nxt = self._dispatch_chunk(cell, idxs, graphs, out)
+                pending = nxt
             if pending is not None:
                 self._retire_chunk(pending, graphs, out)
-            pending = nxt
-        if pending is not None:
-            self._retire_chunk(pending, graphs, out)
+        finally:
+            if pending is not None and pending.inflight is not None:
+                pending.inflight.solving.close()
         self._wall_s += time.perf_counter() - t_wall
 
     def _solve_staged_one(self, g: Graph, cell: ServeCell) -> ServeResult:
@@ -740,18 +764,20 @@ class MWISService:
     def _finish_result(
         self, g: Graph, mask: np.ndarray, check: bool
     ) -> ServeResult:
-        weight = int(g.weights[mask].sum(dtype=np.int64))
-        if check:
-            self.counters["verify_checked"] += 1
-            rep = V.verify_result(g, mask, weight)
-            if not rep.ok:
-                self.counters["verify_failures"] += 1
-                self.events.append(("verify_failure", rep.detail))
-                return ServeResult(
-                    members=mask, weight=weight, ok=False,
-                    reason=rep.reason, error=f"{rep.reason}: {rep.detail}",
-                )
-        return ServeResult(members=mask, weight=weight)
+        with span("mwis.serve.verify"):
+            weight = int(g.weights[mask].sum(dtype=np.int64))
+            if check:
+                self.counters["verify_checked"] += 1
+                rep = V.verify_result(g, mask, weight)
+                if not rep.ok:
+                    self.counters["verify_failures"] += 1
+                    self.events.append(("verify_failure", rep.detail))
+                    return ServeResult(
+                        members=mask, weight=weight, ok=False,
+                        reason=rep.reason,
+                        error=f"{rep.reason}: {rep.detail}",
+                    )
+            return ServeResult(members=mask, weight=weight)
 
     def solve_batch(self, graphs: Sequence[Graph]) -> List[ServeResult]:
         """Solve many instances; results in request order.

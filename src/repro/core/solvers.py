@@ -33,6 +33,7 @@ from repro.core.distributed import (
 )
 from repro.core.local_reduce import local_reduce
 from repro.core.partition import PartitionedGraph
+from repro.core.spans import span
 
 UNDECIDED, INCLUDED, EXCLUDED, FOLDED = 0, 1, 2, 3
 I32_MIN = jnp.iinfo(jnp.int32).min
@@ -60,9 +61,10 @@ def _reduce_to_fixpoint(state, aux, ctx: Ctx, cfg: DisReduConfig,
             backend=cfg.backend, plan=plan,
         )
         state, _ = ctx.exchange(state)
-        changed = ctx.gany(
-            (state.status != snap_s).any() | (state.w != snap_w).any()
-        )
+        with jax.named_scope("mwis.round.vote"):
+            changed = ctx.gany(
+                (state.status != snap_s).any() | (state.w != snap_w).any()
+            )
         return state, rounds + 1, changed
 
     def cond(carry):
@@ -147,8 +149,9 @@ def _rnp_loop(state, aux, ctx: Ctx, cfg: DisReduConfig,
     def body(carry):
         state, it, _ = carry
         state, _ = _reduce_to_fixpoint(state, aux, ctx, cfg, plan=plan)
-        score = peel_score(state, aux, backend=cfg.backend, plan=plan)
-        state = ctx.peel(state, score)
+        with jax.named_scope("mwis.peel"):
+            score = peel_score(state, aux, backend=cfg.backend, plan=plan)
+            state = ctx.peel(state, score)
         remaining = ctx.gany((aux.is_local & (state.status == UNDECIDED)).any())
         return state, it + 1, remaining
 
@@ -358,7 +361,9 @@ def _stage_union_jit(state, is_ghost, aux, halo, plan, *, phase, iters,
                 backend=cfg.backend, plan=plan,
             )
             state, _ = ctx.exchange(state)
-            changed = (state.status != snap_s).any() | (state.w != snap_w).any()
+            with jax.named_scope("mwis.round.vote"):
+                changed = ((state.status != snap_s).any()
+                           | (state.w != snap_w).any())
             return state, rounds + 1, changed
 
         def cond(carry):
@@ -386,8 +391,9 @@ def _stage_union_jit(state, is_ghost, aux, halo, plan, *, phase, iters,
         )
     if phase != "peel":
         raise ValueError(f"unknown stage phase {phase!r}")
-    score = peel_score(state, aux, backend=backend, plan=plan)
-    state = ctx.peel(state, score)
+    with jax.named_scope("mwis.peel"):
+        score = peel_score(state, aux, backend=backend, plan=plan)
+        state = ctx.peel(state, score)
     remaining = (aux.is_local & (state.status == UNDECIDED)).any()
     return state, jnp.zeros((), jnp.int32), remaining
 
@@ -538,19 +544,20 @@ def solve_staged(
 
     def _run_stage(phase_name: str, iters: int):
         nonlocal state
-        t = _time.perf_counter()
-        state, rounds, flag = _stage_union_jit(
-            state, prob.is_ghost, prob.aux, prob.halo, prob.plan,
-            phase=phase_name, iters=int(iters), heavy_k=cfg.heavy_k,
-            use_heavy=cfg.use_heavy, sweeps=cfg.sweeps_per_round, p=pg.p,
-            schedule=cfg.schedule, backend=cfg.backend,
-        )
-        jax.block_until_ready(state.status)
+        took = {}
+        with span("mwis.descent.stage", took, "ms"):
+            state, rounds, flag = _stage_union_jit(
+                state, prob.is_ghost, prob.aux, prob.halo, prob.plan,
+                phase=phase_name, iters=int(iters), heavy_k=cfg.heavy_k,
+                use_heavy=cfg.use_heavy, sweeps=cfg.sweeps_per_round,
+                p=pg.p, schedule=cfg.schedule, backend=cfg.backend,
+            )
+            jax.block_until_ready(state.status)
         if trajectory:
             stages.append(dict(
                 phase=phase_name, shape=path[-1]["cell"], L=int(pg.L),
                 rounds=int(rounds), alive=_alive(),
-                us=round((_time.perf_counter() - t) * 1e6, 1),
+                us=round(took["ms"] * 1e3, 1),
             ))
         return int(rounds), bool(flag)
 

@@ -2,6 +2,7 @@
 hold at tiny sizes in interpret mode; the compile-cache helper."""
 
 import os
+import re
 import subprocess
 import sys
 
@@ -64,7 +65,33 @@ def test_smoke_check_raises_on_a_broken_contract(smoke):
         smoke.check(False, "pallas and jnp differ")
 
 
-def test_compile_cache_leaves_the_env_dir_to_jax(monkeypatch, tmp_path):
+@pytest.fixture
+def cache_key_settings():
+    """Restore the cache-key settings that enable_compile_cache sets."""
+    keys = ("jax_compilation_cache_include_metadata_in_key",
+            "jax_hlo_source_file_canonicalization_regex")
+    before = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_keys_on_op_metadata(monkeypatch, tmp_path,
+                                           cache_key_settings):
+    # the programs' mwis.* scopes live only in op metadata: a cache key
+    # without it would hand back a program compiled with other names
+    from repro.launch import cache
+
+    monkeypatch.setenv(cache.ENV, str(tmp_path))
+    cache.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    regex = jax.config.jax_hlo_source_file_canonicalization_regex
+    assert re.sub(regex, "", "/any/checkout/src/repro/core/engine.py") == (
+        "src/repro/core/engine.py")
+
+
+def test_compile_cache_leaves_the_env_dir_to_jax(monkeypatch, tmp_path,
+                                                 cache_key_settings):
     from repro.launch import cache
 
     before = jax.config.jax_compilation_cache_dir
@@ -73,7 +100,8 @@ def test_compile_cache_leaves_the_env_dir_to_jax(monkeypatch, tmp_path):
     assert jax.config.jax_compilation_cache_dir == before
 
 
-def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+def test_compile_cache_defaults_to_the_checkout(monkeypatch,
+                                                cache_key_settings):
     from repro.launch import cache
 
     monkeypatch.delenv(cache.ENV, raising=False)
