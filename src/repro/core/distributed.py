@@ -114,6 +114,9 @@ def pack_union_problem(
         win_complete=pg.win_complete.reshape(-1),
         win_adj_bits=pg.win_adj_bits.reshape(p * V, -1),
         edge_common=edge_common,
+        # per-PE masks hold gids and flags, not slots: offsets leave them
+        **{k: getattr(pg, k).reshape((-1,) + getattr(pg, k).shape[2:])
+           for k in R.STATIC_MASKS},
     )
     plan = None
     if backend != "jnp":
@@ -272,6 +275,9 @@ def _unpack_per_pe(pg: PartitionedGraph, keys, args):
         is_iface=a["is_iface"], owner_rank=a["owner_pe"],
         window=a["window"], win_complete=a["win_complete"],
         win_adj_bits=a["win_adj_bits"], edge_common=a["edge_common"],
+        # built once per call, before the program's loops
+        **R.static_masks(a["row"], a["col"], a["gid"], a["is_local"],
+                         a["win_complete"], a["window"], a["edge_common"]),
     )
     L, G = pg.L, pg.G
     halo = X.Halo(

@@ -731,7 +731,7 @@ def compute_ctx(
 
     if need_bits:
         if backend == "jnp":
-            act_bits = W.window_active_bits(active, aux.gid, aux.window)
+            act_bits = W.window_active_bits(active, aux.win_real, aux.window)
             if "clique" in requires:
                 clique = W.window_clique_ok(act_bits, aux.win_adj_bits)
         else:

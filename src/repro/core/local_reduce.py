@@ -37,6 +37,7 @@ def make_aux(pg: PartitionedGraph, pe: int | None = None) -> R.Aux:
         owner_rank=take(pg.owner_pe),
         window=take(pg.window), win_complete=take(pg.win_complete),
         win_adj_bits=take(pg.win_adj_bits), edge_common=take(pg.edge_common),
+        **{k: take(getattr(pg, k)) for k in R.STATIC_MASKS},
     )
 
 

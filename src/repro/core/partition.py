@@ -32,6 +32,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro.core import rules as R
 from repro.core.graph import Graph
 
 # Status codes shared with the JAX rules.
@@ -77,6 +78,14 @@ class PartitionedGraph:
     Dc: int
     send_slot: np.ndarray       # [p, p, S] int32 board slots to send (pad=B)
     recv_ghost: np.ndarray      # [p, p, S] int32 ghost idx to scatter (pad=G)
+    # the rule tests' static factors (rules.static_masks), built once per
+    # graph; None only in a shapes-only (dry-run) graph
+    e_local: Optional[np.ndarray] = None   # [p, E] bool
+    e_up: Optional[np.ndarray] = None      # [p, E] bool
+    ec_ok: Optional[np.ndarray] = None     # [p, E, Dc] bool
+    gid_col: Optional[np.ndarray] = None   # [p, E] int32
+    wc_col: Optional[np.ndarray] = None    # [p, E] bool
+    win_real: Optional[np.ndarray] = None  # [p, V, D] bool
 
     @property
     def V(self) -> int:
@@ -101,6 +110,15 @@ class PartitionedGraph:
             win_adj_bits=self.win_adj_bits, edge_common=self.edge_common,
             send_slot=self.send_slot, recv_ghost=self.recv_ghost,
         )
+
+
+def _static_masks(row, col, gid, is_local, win_complete, window,
+                  edge_common) -> Dict[str, np.ndarray]:
+    """:func:`repro.core.rules.static_masks` of every PE, stacked."""
+    per = [R.static_masks(row[i], col[i], gid[i], is_local[i],
+                          win_complete[i], window[i], edge_common[i])
+           for i in range(row.shape[0])]
+    return {k: np.stack([m[k] for m in per]) for k in R.STATIC_MASKS}
 
 
 def _block_starts(g: Graph, p: int, edge_balanced: bool) -> np.ndarray:
@@ -322,6 +340,8 @@ def partition_graph(
         win_complete=win_complete, win_adj_bits=win_adj_bits,
         edge_common=edge_common, Dc=Dc,
         send_slot=send_slot, recv_ghost=recv_ghost,
+        **_static_masks(row, col, gid, is_local, win_complete, window,
+                        edge_common),
     )
 
 
@@ -484,6 +504,8 @@ def compact_partition(
         win_complete=win_complete, win_adj_bits=win_adj_bits,
         edge_common=edge_common, Dc=Dc,
         send_slot=send_slot, recv_ghost=recv_ghost,
+        **_static_masks(row, col, gid, is_local, win_complete, window,
+                        edge_common),
     )
 
 

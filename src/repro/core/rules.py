@@ -34,6 +34,11 @@ border data only ever makes a rule *more conservative*, never unsound.
 Interface-vertex includes are proposals (Remark 4.6); conflict resolution
 happens in the exchange step (:mod:`repro.core.distributed`).
 
+Static-factor contract: every factor of a rule test that depends on the
+graph alone (endpoint locality, gid order, the extended single-edge target
+filter, ...) is computed once per graph by :func:`static_masks` and carried
+in :class:`Aux`; inside the sweep loop rules gather only state.
+
 Aggregate-declaration contract (see ARCHITECTURE.md): every rule *declares*
 the neighborhood aggregates its TEST needs via ``@_requires(...)`` and
 receives them in a :class:`SweepCtx` — rules never issue their own segment
@@ -51,6 +56,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ops import segment_max, segment_sum
 
 from repro.kernels.wedge_intersect.ops import window_active_bits
@@ -78,7 +84,12 @@ I32_MIN = jnp.iinfo(jnp.int32).min
 
 
 class Aux(NamedTuple):
-    """Static (per-PE) graph structure; never modified by reductions."""
+    """Static (per-PE) graph structure; never modified by reductions.
+
+    The last six fields are the rule tests' static factors, functions of
+    the fields above them alone (:func:`static_masks` defines them); they
+    are built once per graph so that no sweep gathers static data again.
+    """
 
     row: jax.Array            # [E] i32 source local idx (pad = nil)
     col: jax.Array            # [E] i32 target local idx (pad = nil)
@@ -90,6 +101,39 @@ class Aux(NamedTuple):
     win_complete: jax.Array   # [V] bool
     win_adj_bits: jax.Array   # [V, D] i32 static pairwise adjacency bits
     edge_common: jax.Array    # [E, Dc] i32 capped common neighborhoods
+    e_local: jax.Array        # [E] bool  is_local[row] & is_local[col]
+    e_up: jax.Array           # [E] bool  gid[row] > gid[col]
+    ec_ok: jax.Array          # [E, Dc] bool  extended single-edge targets
+    gid_col: jax.Array        # [E] i32  gid[col]
+    wc_col: jax.Array         # [E] bool  win_complete[col]
+    win_real: jax.Array       # [V, D] bool  gid[window] >= 0
+
+
+#: The :class:`Aux` fields :func:`static_masks` builds.
+STATIC_MASKS = ("e_local", "e_up", "ec_ok", "gid_col", "wc_col", "win_real")
+
+
+def static_masks(row, col, gid, is_local, win_complete, window,
+                 edge_common) -> dict:
+    """The rule tests' static factors of one graph, keyed by Aux field.
+
+    Works on numpy (host packing) and jax arrays (inside a traced
+    program) alike; index arrays address one PE's (or one union's) slots.
+    ``ec_ok`` is extended single-edge's whole static target filter: a
+    common neighbor is a real local vertex below both endpoints in gid.
+    """
+    xp = np if isinstance(row, np.ndarray) else jnp
+    g_row, g_col = gid[row], gid[col]
+    g_ec = gid[edge_common]
+    low = xp.minimum(g_row, g_col)
+    return dict(
+        e_local=is_local[row] & is_local[col],
+        e_up=g_row > g_col,
+        ec_ok=is_local[edge_common] & (g_ec >= 0) & (g_ec < low[:, None]),
+        gid_col=g_col,
+        wc_col=win_complete[col],
+        win_real=gid[window] >= 0,
+    )
 
 
 class RedState(NamedTuple):
@@ -145,7 +189,7 @@ def _accept_independent(
     aux: Aux, eact: jax.Array, cand: jax.Array, V: int
 ) -> jax.Array:
     """Filter include candidates to an independent set (gid priority)."""
-    nbr_cand_gid = jnp.where(eact & cand[aux.col], aux.gid[aux.col], -1)
+    nbr_cand_gid = jnp.where(eact & cand[aux.col], aux.gid_col, -1)
     m = segment_max(nbr_cand_gid, aux.row, num_segments=V)
     m = jnp.maximum(m, -1)
     return cand & (aux.gid > m)
@@ -228,7 +272,7 @@ def rule_degree_one(state: RedState, aux: Aux, ctx: SweepCtx) -> RedState:
     #       w(u) -= w(v);  v FOLDED;  v ∈ I  iff  u ∉ I.
     active = _active(state)
     cand = aux.is_local & active & (deg == 1) & (state.w < w_u)
-    cand &= aux.is_local[only] & active[only]
+    cand &= (aux.is_local & active)[only]
     # one fold per target u per sweep: keep the max-gid candidate
     tgt = jnp.where(cand, only, V - 1)
     best = jnp.full(V, -1, jnp.int32).at[tgt].max(jnp.where(cand, aux.gid, -1))
@@ -293,7 +337,7 @@ def rule_weight_transfer(state: RedState, aux: Aux,
     # whose simpliciality we cannot decide (incomplete window) blocks v.
     simpl_known = aux.win_complete & clique
     nbr_blocks = eact & (state.w[aux.col] > state.w[aux.row]) & (
-        simpl_known[aux.col] | ~aux.win_complete[aux.col]
+        simpl_known[aux.col] | ~aux.wc_col
     )
     blocked = segment_max(
         nbr_blocks.astype(jnp.int32), aux.row, num_segments=V
@@ -305,7 +349,7 @@ def rule_weight_transfer(state: RedState, aux: Aux,
     )
     # unique within two hops (gid priority) => disjoint closed neighborhoods
     m1 = segment_max(
-        jnp.where(eact & cand[aux.col], aux.gid[aux.col], -1), aux.row,
+        jnp.where(eact & cand[aux.col], aux.gid_col, -1), aux.row,
         num_segments=V,
     )
     m1 = jnp.maximum(m1, -1)
@@ -316,7 +360,7 @@ def rule_weight_transfer(state: RedState, aux: Aux,
     # apply the fold: remove X = {u in N[v]: w(u) <= w(v)}, transfer weight.
     # entry activity here must be FRESH (application, not test): recompute
     # from current status via the vectorized window helper, not from ctx
-    fresh_bits = window_active_bits(_active(state), aux.gid, aux.window)
+    fresh_bits = window_active_bits(_active(state), aux.win_real, aux.window)
     wv = state.w
     tgt = aux.window  # [V, D]
     ent_active = ((fresh_bits[:, None] >> jnp.arange(D)[None, :]) & 1) == 1
@@ -354,15 +398,13 @@ def rule_basic_single_edge(state: RedState, aux: Aux,
     aw = _aw(state, active)
     s = ctx.S
     # capped common-neighborhood weight (lower bound => conservative)
-    c = jnp.where(
-        active[aux.edge_common], aw[aux.edge_common], 0
-    ).sum(axis=1)
+    c = aw[aux.edge_common].sum(axis=1)  # aw is 0 off the active set
     val = s[aux.row] - c  # >= true ω(N(u) \ N(v)) which contains v itself
     test = (
         eact
-        & aux.is_local[aux.row] & aux.is_local[aux.col]
+        & aux.e_local
         & (val <= state.w[aux.row])
-        & (aux.gid[aux.row] > aux.gid[aux.col])  # ascending certificate chain
+        & aux.e_up  # ascending certificate chain
     )
     excl = segment_max(test.astype(jnp.int32), aux.col, num_segments=V) > 0
     status = jnp.where(
@@ -386,17 +428,11 @@ def rule_extended_single_edge(state: RedState, aux: Aux,
     # edge e = (v=row, u=col):  w(v) >= S(v) - aw(u)  => exclude common nbrs
     test = (
         eact
-        & aux.is_local[aux.row] & aux.is_local[aux.col]
+        & aux.e_local
         & (s[aux.row] - aw[aux.col] <= state.w[aux.row])
     )
-    min_gid = jnp.minimum(aux.gid[aux.row], aux.gid[aux.col])
     tgt = aux.edge_common  # [E, Dc]
-    upd = (
-        test[:, None]
-        & active[tgt] & aux.is_local[tgt]
-        & (aux.gid[tgt] < min_gid[:, None])
-        & (aux.gid[tgt] >= 0)
-    )
+    upd = test[:, None] & active[tgt] & aux.ec_ok
     nil_slot = V - 1
     status = state.status.at[jnp.where(upd, tgt, nil_slot)].set(jnp.int8(EXCLUDED))
     fired = upd.any()
@@ -416,7 +452,7 @@ def _alpha_neighborhood(
     V, D = aux.window.shape
     K = heavy_k
     active = status == UNDECIDED
-    ent_ok = active[aux.window] & (aux.gid[aux.window] >= 0)  # [V, D]
+    ent_ok = active[aux.window] & aux.win_real  # [V, D]
     # stable-sort entries: active first, keep the first K
     order = jnp.argsort(~ent_ok, axis=1, stable=True)[:, :K]  # [V, K]
     ent = jnp.take_along_axis(aux.window, order, axis=1)      # [V, K]
@@ -478,8 +514,7 @@ def reconstruct_members(state: RedState, aux: Aux) -> jax.Array:
         v = state.log_v[k]
         u = state.log_u[k]
         fold1_val = ~in_set[u]
-        wt_entries = aux.window[v]
-        wt_val = ~(in_set[wt_entries] & (aux.gid[wt_entries] >= 0)).any()
+        wt_val = ~(in_set[aux.window[v]] & aux.win_real[v]).any()
         val = jnp.where(kind == LOG_FOLD1, fold1_val, wt_val)
         return in_set.at[jnp.where(live, v, nil)].set(live & val)
 

@@ -54,16 +54,17 @@ def common_neighbor_stats(
 
 
 def window_active_bits(
-    active: jax.Array,   # [V] bool (status == UNDECIDED)
-    gid: jax.Array,      # [V] i32 global ids (pad/nil = -1)
-    window: jax.Array,   # [V, D] capped neighbor lists
+    active: jax.Array,    # [V] bool (status == UNDECIDED)
+    win_real: jax.Array,  # [V, D] bool static: gid[window] >= 0
+    window: jax.Array,    # [V, D] capped neighbor lists
 ) -> jax.Array:
     """[V] i32 — bit i set iff window[v, i] is an active real vertex.
 
-    Vectorized form of the seed's D-unrolled loop: one [V, D] gather, bits
-    are disjoint per position so the OR is a plain sum."""
+    Vectorized form of the seed's D-unrolled loop: one [V, D] gather of the
+    activity (the real-vertex mask is static), bits are disjoint per
+    position so the OR is a plain sum."""
     D = window.shape[1]
-    ent_ok = active[window] & (gid[window] >= 0)               # [V, D]
+    ent_ok = active[window] & win_real                          # [V, D]
     shifts = jnp.arange(D, dtype=jnp.int32)[None, :]
     return (ent_ok.astype(jnp.int32) << shifts).sum(axis=1)
 
