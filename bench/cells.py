@@ -9,11 +9,19 @@ Inputs are a fixed set named by the traffic file (``graph_seeds`` or
 ``pool_seed``); the run's seed orders them, so every run does the same work
 and a window always ends on a whole pass.  Every answer of the window is
 compared.
+
+A traffic ``kind`` is found by name: one of ``KINDS`` below, else the
+``Cell`` of ``bench/kinds/<kind>.py``.  A graph cell's configuration names
+its graph ``family`` (``gnm`` where it names none), whose ``generate`` is in
+``bench/families/<family>.py``.  So a new cell kind or graph family joins
+the benchmark as a new file.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
+import os
 import time
 from contextlib import ExitStack, contextmanager
 from typing import Dict, List, NamedTuple, Tuple
@@ -21,6 +29,19 @@ from typing import Dict, List, NamedTuple, Tuple
 import numpy as np
 
 from bench import graphs, reference as ref
+
+#: the checkout whose ``bench/`` holds the files found by name
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def by_name(folder: str, name: str, root: str = ROOT):
+    """The module ``bench/<folder>/<name>.py`` under ``root``."""
+    path = os.path.join(root, "bench", folder, name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{folder}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Spans:
@@ -92,11 +113,15 @@ def reference_reducer(g: graphs.Csr, p: int, config: dict, **kw):
 
 
 class Cell:
-    """Common shape of a driver; subclasses fill the four phases."""
+    """Common shape of a cell kind; a subclass fills its phases: ``setup``,
+    ``window``, ``take_answers``, ``check``, ``attempted``, ``e2e`` and
+    ``control_answers`` (with ``make_inputs`` where the control needs
+    it).  ``root`` is the checkout whose files it finds by name."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int, spans: Spans):
+    def __init__(self, config: dict, traffic: dict, seed: int, spans: Spans,
+                 root: str = ROOT):
         self.config, self.traffic, self.seed = config, traffic, seed
-        self.spans = spans
+        self.spans, self.root = spans, root
         self.counters: Dict[str, float] = {}
         self.window_s = 0.0
 
@@ -112,18 +137,16 @@ class Cell:
 
 
 class GraphCell(Cell):
-    """Cells over a fixed set of GNM graphs (reduce, reduce_mesh, rnp)."""
+    """Cells over a fixed set of graphs of the configuration's family
+    (reduce, reduce_mesh, rnp)."""
 
     def make_inputs(self) -> None:
         """The fixed graphs and their order."""
         t = self.traffic
-        n = t["pes"] * t["n_per_pe"]
-        m = n * self.config["edges_per_vertex"]
+        generate = family(self.config.get("family", "gnm"), self.root)
         rng = graphs.rng_of(self.seed)
         self.order = [int(i) for i in rng.permutation(len(t["graph_seeds"]))]
-        self.graphs = [graphs.gnm(n, m, s,
-                                  self.config["weight_lo"],
-                                  self.config["weight_hi"])
+        self.graphs = [generate(t["pes"] * t["n_per_pe"], self.config, s)
                        for s in t["graph_seeds"]]
 
     def build_graphs(self) -> None:
@@ -562,3 +585,15 @@ class ServeCell(Cell):
 
 KINDS = {"reduce": ReduceCell, "reduce_mesh": MeshReduceCell,
          "rnp": RnpCell, "serve": ServeCell}
+
+
+def kind(name: str, root: str = ROOT):
+    """The cell class of the traffic kind ``name``: a built-in one, else the
+    ``Cell`` of ``bench/kinds/<name>.py``."""
+    return KINDS.get(name) or by_name("kinds", name, root).Cell
+
+
+def family(name: str, root: str = ROOT):
+    """``generate(n, config, seed) -> graphs.Csr`` of the graph family
+    ``name``, from ``bench/families/<name>.py``."""
+    return by_name("families", name, root).generate

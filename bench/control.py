@@ -33,8 +33,9 @@ def control(workload: str, seed: int, root: str = ROOT, traffic=None,
 
     spec = spec or load_json(os.path.join(root, "BENCHMARK.json"))
     _, config, cell_traffic = cell_spec(spec, workload, root)
-    cell = C.KINDS[cell_traffic["kind"]](config, traffic or cell_traffic,
-                                         seed, C.Spans())
+    traffic = traffic or cell_traffic
+    cell = C.kind(traffic["kind"], root)(config, traffic, seed, C.Spans(),
+                                         root=root)
     cell.make_inputs()
     cell.control_answers()
     gaps, failed = cell.check()

@@ -4,15 +4,17 @@
 
 The cell, its configuration and its traffic come from ``BENCHMARK.json`` at
 the root of the checkout and the files it names: ``bench/configs/<config>
-.json`` and ``bench/traffic/<traffic>.json``; a per-layer metric ``<name>``
-is read by ``bench/metrics/<name>.py``.  The run loads, warms up (that is
+.json`` and ``bench/traffic/<traffic>.json``; the traffic's ``kind`` names
+the cell class (``bench/cells.py`` ``kind``); a per-layer metric ``<name>`` is
+read by ``bench/metrics/<name>.py``.  The run loads, warms up (that is
 ``setup_s``), measures for ``--seconds`` in whole passes over the cell's
 inputs, reads the device's peak memory, frees the program's state and then
 compares the answers with the plain reference (``bench/reference.py``).
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` they are its per-layer ones, and the window's first call is
-traced (the traced window).
+traced (the traced window) into a directory of the run's own under
+``.bench_trace/``, removed once the metrics are read.
 The last line of stdout is the result; the numbers compared, each beside
 its limit, are the last lines of stderr and the result's last key.  The run
 exits 1, printing no result, when JAX finds no TPU or fewer chips than the
@@ -26,10 +28,12 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import shutil  # noqa: E402
 import sys  # noqa: E402
+import tempfile  # noqa: E402
+from contextlib import contextmanager  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -69,22 +73,21 @@ def metric_sets(spec: dict, workload: str):
 
 def reader(name: str, root: str = ROOT):
     """The ``read`` function of the per-layer metric ``name``."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    from bench.cells import by_name
+
+    return by_name("metrics", name, root).read
 
 
 class Run:
     """What a per-layer reader reads: the cell's counters and host spans,
-    its window, and the trace summary of a traced run."""
+    its window, and the trace summary of a traced run with the directory
+    its trace is in."""
 
-    def __init__(self, workload, cell, spans, summary):
+    def __init__(self, workload, cell, spans, summary, trace_dir):
         self.workload, self.cell, self.spans = workload, cell, spans
         self.counters, self.window_s = cell.counters, cell.window_s
-        self.summary = summary
+        self.summary, self.trace_dir = summary, trace_dir
+        self.scopes = None      # bench/scopes.py's summary, once read
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool) -> dict:
@@ -98,17 +101,44 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool) -> dict:
 
 def measure(spec: dict, cell_entry: dict, config: dict, traffic: dict,
             seed: int, seconds: float, trace: bool,
-            require_chip: bool = True, started: float = None) -> dict:
+            require_chip: bool = True, started: float = None,
+            root: str = ROOT) -> dict:
     """Set up, measure, check and return the result record.
-    ``require_chip=False`` skips the look for a chip (CPU rehearsals).  The
-    compile-cache settings and the compile listener are the process's own
-    again when it returns."""
+    ``require_chip=False`` skips the look for a chip (CPU rehearsals);
+    ``root`` is the checkout whose kind, family and metric files are
+    found by name.  A traced run writes its trace into a directory of its
+    own, removed on return.  The compile-cache settings and the compile
+    listener are the process's own again when it returns."""
+    started = time.perf_counter() if started is None else started
+    with own_trace_dir(trace) as trace_dir:
+        return _measure(spec, cell_entry, config, traffic, seed, seconds,
+                        trace_dir, require_chip, started, root)
+
+
+@contextmanager
+def own_trace_dir(trace: bool):
+    """A new directory under ``.bench_trace/`` for a traced run (None for
+    an untraced one), removed on exit: runs at once never share one."""
+    if not trace:
+        yield None
+        return
+    traces = os.path.join(ROOT, ".bench_trace")
+    os.makedirs(traces, exist_ok=True)
+    trace_dir = tempfile.mkdtemp(prefix="run-", dir=traces)
+    try:
+        yield trace_dir
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+
+
+def _measure(spec, cell_entry, config, traffic, seed, seconds, trace_dir,
+             require_chip, started, root) -> dict:
     import jax
 
     from bench import cells as C, devices, trace as T
 
-    started = time.perf_counter() if started is None else started
     workload, chips = cell_entry["name"], cell_entry["chips"]
+    trace = trace_dir is not None
     if require_chip:
         device = devices.gate(chips)
     else:
@@ -117,7 +147,9 @@ def measure(spec: dict, cell_entry: dict, config: dict, traffic: dict,
                       count=len(jax.devices()))
     settings = dict(jax_compilation_cache_dir=os.environ.get(
         "JAX_COMPILATION_CACHE_DIR") or os.path.join(ROOT, ".jax_cache"),
-        jax_persistent_cache_min_compile_time_secs=0)
+        jax_persistent_cache_min_compile_time_secs=0,
+        # the programs name their layers only in op metadata
+        jax_compilation_cache_include_metadata_in_key=True)
     before = {k: getattr(jax.config, k) for k in settings}
     compiles = []
 
@@ -129,13 +161,13 @@ def measure(spec: dict, cell_entry: dict, config: dict, traffic: dict,
         jax.config.update(k, v)
     jax.monitoring.register_event_duration_secs_listener(on_event)
     try:
-        trace_dir = os.path.join(ROOT, ".bench_trace")
         spans = C.Spans()
-        cell = C.KINDS[traffic["kind"]](config, traffic, seed, spans)
+        cell = C.kind(traffic["kind"], root)(config, traffic, seed, spans,
+                                             root=root)
         cell.setup()
         setup_s = time.perf_counter() - started
         n_compiles = len(compiles)
-        spans.trace_dir = trace_dir if trace else None
+        spans.trace_dir = trace_dir
         cell.window(seconds)
         cell.counters["window_compiles"] = len(compiles) - n_compiles
     finally:
@@ -157,9 +189,9 @@ def measure(spec: dict, cell_entry: dict, config: dict, traffic: dict,
     e2e, layer = metric_sets(spec, workload)
     metrics = {}
     if trace:
-        run = Run(workload, cell, spans, summary)
+        run = Run(workload, cell, spans, summary, trace_dir)
         for m in layer:
-            v = reader(m["name"])(run)
+            v = reader(m["name"], root)(run)
             if v is not None:
                 metrics[m["name"]] = dict(value=v, unit=m["unit"])
     else:

@@ -38,9 +38,6 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 from bench import trace
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-#: where ``bench/run.py`` writes the traced window
-TRACE_DIR = os.path.join(os.path.dirname(HERE), ".bench_trace")
 CONTAINERS = trace.CONTAINERS + ("cond",)
 #: the stat of a device op's metadata that holds its op name (a v5e trace
 #: carries it: ``jit(f)/while/body/mwis.aggregate/gather:``)
@@ -422,21 +419,11 @@ def self_s(summary: dict, span: str) -> Optional[float]:
     return total / 1e9
 
 
-_CACHE: Dict[Tuple[str, float, int], dict] = {}
-
-
 def of_run(run) -> Optional[dict]:
-    """The scope summary of a traced run, read once per trace file; None
-    for an untraced run."""
+    """The scope summary of a traced run (read from its own trace
+    directory once, then kept on the run); None for an untraced run."""
     if not run.summary:
         return None
-    out_dir = getattr(run.spans, "trace_dir", None) or TRACE_DIR
-    paths = sorted(glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
-                             recursive=True))
-    if not paths:
-        return None
-    key = (paths[-1], os.path.getmtime(paths[-1]), run.summary["devices"])
-    if key not in _CACHE:
-        _CACHE.clear()
-        _CACHE[key] = summarize(load(out_dir), run.summary["devices"])
-    return _CACHE[key]
+    if run.scopes is None:
+        run.scopes = summarize(load(run.trace_dir), run.summary["devices"])
+    return run.scopes

@@ -1,6 +1,7 @@
 """CPU rehearsal of every cell: traffic, warm-up, window, comparison with
 the reference and the result line, at a small size."""
 
+import hashlib
 import json
 import os
 import shutil
@@ -9,22 +10,23 @@ import sys
 
 import pytest
 
-from bench import devices, run as R
-from bench.tests.helpers import (LATER_CELL, ROOT, SMALL, in_four_devices,
-                                 measure_small)
+from bench import cells as C, control, devices, run as R
+from bench.tests.helpers import (ROOT, SMALL_DIR, in_four_devices,
+                                 load_spec, measure_small, small_cell,
+                                 small_path)
 
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks",
         "counters"]
+CELLS = load_spec()["workloads"]
 
 
-@pytest.mark.parametrize("workload", ["gnm18.reduce", "gnm11.rnp",
-                                      "serve.mix16"])
+@pytest.mark.parametrize("workload", [c["name"] for c in CELLS
+                                      if c["chips"] == 1])
 def test_cell_runs_and_matches_the_reference(workload):
     out = measure_small(workload)
     assert list(out) == KEYS
     assert out["correct"] and out["failed"] == 0 and out["attempted"] > 0
-    spec = R.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    e2e, _ = R.metric_sets(spec, workload)
+    e2e, _ = R.metric_sets(load_spec(), workload)
     assert sorted(out["metrics"]) == sorted(m["name"] for m in e2e)
     assert all(v["value"] > 0 for v in out["metrics"].values())
     assert all(c["value"] == 0 for c in out["checks"].values())
@@ -51,38 +53,113 @@ def test_four_pe_cell_on_four_cpu_devices():
     assert out == dict(correct=True, compiles=0)
 
 
+def test_compile_cache_settings_are_the_processs_own_again(monkeypatch):
+    """The run keys the compile cache on op metadata, where the programs'
+    scopes live, and gives the process its own settings back."""
+    import jax
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_compilation_cache_include_metadata_in_key")
+    before = {k: getattr(jax.config, k) for k in keys}
+    seen = {}
+    window = C.RnpCell.window
+
+    def seeing(self, seconds):
+        seen.update({k: getattr(jax.config, k) for k in keys})
+        window(self, seconds)
+
+    monkeypatch.setattr(C.RnpCell, "window", seeing)
+    assert measure_small("gnm11.rnp")["correct"]
+    assert seen["jax_compilation_cache_include_metadata_in_key"] is True
+    assert seen["jax_persistent_cache_min_compile_time_secs"] == 0
+    assert {k: getattr(jax.config, k) for k in keys} == before
+
+
+#: the files a later change adds for a new graph family and cell kind
+FAMILY = """
+import numpy as np
+
+from bench import graphs
+
+
+def generate(n, config, seed):
+    pairs = np.stack([np.arange(n - 1), np.arange(1, n)], axis=1)
+    w = graphs.rng_of(seed).integers(config["weight_lo"],
+                                     config["weight_hi"] + 1, size=n)
+    return graphs.csr(n, pairs, w)
+"""
+KIND = """
+from bench.cells import ReduceCell
+
+
+class Cell(ReduceCell):
+    def setup(self):
+        super().setup()
+        self.counters["graphs_set_up"] = len(self.graphs)
+"""
+READER = """
+def read(run):
+    return run.cell.graphs[0][2].shape[0]
+"""
+
+
+def file_hashes(top):
+    return {str(p.relative_to(top)): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in top.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
 def test_new_files_are_found_by_name(tmp_path):
-    """A configuration, traffic mix or metric reader that a later change
-    adds is found from BENCHMARK.json by its name; no file is edited."""
+    """A graph family, a cell kind, a configuration, a traffic mix, its
+    small size and a metric reader that a later change adds are found from
+    BENCHMARK.json by name: a copy of the benchmark with only those files
+    and entries added runs the new cell, untraced and traced, and its
+    control, and no file that was there is edited."""
+    root = str(tmp_path)
     shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
-                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    spec = R.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    conf = json.load(open(tmp_path / "bench/configs/gnm-d8.json"))
-    json.dump(dict(conf, name="gnm-d8b"),
-              open(tmp_path / "bench/configs/gnm-d8b.json", "w"))
-    json.dump(dict(kind="reduce", pes=1, n_per_pe=64, graph_seeds=[9]),
-              open(tmp_path / "bench/traffic/tiny.json", "w"))
-    (tmp_path / "bench/metrics/calls_seen.reduce.py").write_text(
-        "def read(run):\n    return run.counters['calls']\n")
-    spec["configs"].append(dict(name="gnm-d8b", source="x",
-                                file="bench/configs/gnm-d8b.json",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = file_hashes(tmp_path / "bench")
+    spec = load_spec()
+    conf = json.loads((tmp_path / "bench/configs/gnm-d8.json").read_text())
+    new = {"configs/path-d2.json": json.dumps(dict(conf, name="path-d2",
+                                                   family="path")),
+           "traffic/path64.json": json.dumps(dict(
+               kind="reduce_counted", pes=1, n_per_pe=64, graph_seeds=[9])),
+           "tests/small/path.reduce.json": json.dumps(dict(n_per_pe=32)),
+           "families/path.py": FAMILY, "kinds/reduce_counted.py": KIND,
+           "metrics/vertices.reduce.py": READER}
+    for rel, text in new.items():
+        os.makedirs(os.path.dirname(tmp_path / "bench" / rel), exist_ok=True)
+        (tmp_path / "bench" / rel).write_text(text)
+    spec["configs"].append(dict(name="path-d2", source="x",
+                                file="bench/configs/path-d2.json",
                                 reduced=[], why="x"))
-    spec["workloads"].append(dict(name="b.tiny", config="gnm-d8b",
-                                  traffic="tiny", chips=1, why="x"))
-    spec["end_to_end"][0]["workloads"].append("b.tiny")
-    spec["per_layer"].append(dict(name="calls_seen.reduce", unit="1",
+    spec["workloads"].append(dict(name="path.reduce", config="path-d2",
+                                  traffic="path64", chips=1, why="x"))
+    next(m for m in spec["end_to_end"]
+         if m["name"] == "reduce_s")["workloads"].append("path.reduce")
+    spec["per_layer"].append(dict(name="vertices.reduce", unit="1",
                                   better="higher", source="host_clock",
-                                  layer="round driver", moves="reduce_s"))
-    cell, config, traffic = R.cell_spec(spec, "b.tiny", str(tmp_path))
-    assert config["name"] == "gnm-d8b" and traffic["n_per_pe"] == 64
-    e2e, layer = R.metric_sets(spec, "b.tiny")
-    assert "reduce_s" in {m["name"] for m in e2e}
-    assert "calls_seen.reduce" in {m["name"] for m in layer}
+                                  layer="host build", moves="reduce_s",
+                                  workloads=["path.reduce"]))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
 
-    class Run:
-        counters = dict(calls=7)
-
-    assert R.reader("calls_seen.reduce", str(tmp_path))(Run) == 7
+    _, _, config, traffic = small_cell("path.reduce", root)
+    assert config["family"] == "path" and traffic["n_per_pe"] == 32
+    out = measure_small("path.reduce", root=root)
+    assert out["correct"] and out["counters"]["graphs_set_up"] == 1
+    assert set(out["metrics"]) == {"reduce_s", "setup_s"}
+    assert all(c["value"] == 0 for c in out["checks"].values())
+    out = measure_small("path.reduce", root=root, trace=True)
+    assert out["correct"]
+    assert out["metrics"]["vertices.reduce"] == dict(value=32, unit="1")
+    ctl = control.control("path.reduce", 1, root, traffic=traffic)
+    assert set(ctl["checks"]) == set(out["checks"])
+    assert not ctl["correct"], ctl["checks"]
+    after = file_hashes(tmp_path / "bench")
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == set(new)
 
 
 def test_measurement_refuses_a_cpu(capsys):
@@ -103,9 +180,14 @@ def test_exits_without_the_program(tmp_path):
     assert out.returncode != 0 and out.stdout == ""
 
 
-@pytest.mark.parametrize("workload", sorted(SMALL))
+SMALL = [f[:-len(".json")] for f in os.listdir(os.path.join(ROOT, SMALL_DIR))]
+
+
+@pytest.mark.parametrize("workload", sorted({c["name"] for c in CELLS}
+                                            | set(SMALL)))
 def test_small_sizes_name_real_cells(workload):
-    spec = R.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = {c["name"] for c in spec["workloads"]}
-    assert workload in cells or workload == LATER_CELL["name"]
-    assert set(SMALL) >= cells
+    """Every cell has a small size for the CPU, and every small size names
+    a cell and overrides keys of its traffic file."""
+    _, _, traffic = R.cell_spec(load_spec(), workload)
+    assert os.path.isfile(small_path(workload))
+    assert set(R.load_json(small_path(workload))) <= set(traffic)

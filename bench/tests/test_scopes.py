@@ -10,7 +10,7 @@ import os
 import pytest
 
 from bench import scopes as S
-from bench.tests.helpers import measure_small
+from bench.tests.helpers import child_result, measure_small, start_child
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 
@@ -312,3 +312,62 @@ def test_serve_verify_metric_on_a_traced_cpu_run():
     assert out["correct"]
     v = out["metrics"]["verify_ms_per_inst.serve"]
     assert v["unit"] == "ms" and v["value"] > 0
+
+
+#: a child's traced run, held where it has opened its trace until the
+#: other child has too, and before it reads its trace until the other
+#: child's trace is written too
+AT_ONCE = """
+import json, os, time
+from contextlib import contextmanager
+from bench import trace
+from bench.tests.helpers import measure_small
+
+capture, load = trace.capture, trace.load
+
+
+def wait(stage):
+    d = os.path.join({barrier!r}, stage)
+    os.makedirs(d, exist_ok=True)
+    open(os.path.join(d, {workload!r}), "w").close()
+    t0 = time.time()
+    while len(os.listdir(d)) < 2:
+        assert time.time() - t0 < 600, "the other run never got to " + stage
+        time.sleep(0.05)
+
+
+@contextmanager
+def held(out_dir):
+    with capture(out_dir):
+        wait("open")
+        yield
+
+
+def loaded(out_dir):
+    wait("written")
+    return load(out_dir)
+
+
+trace.capture, trace.load = held, loaded
+out = measure_small({workload!r}, trace=True)
+print(json.dumps(dict(correct=out["correct"], metrics=sorted(out["metrics"]),
+                      window_s=out["device"]["window_s"],
+                      call_s=out["counters"]["call_s"][0])))
+"""
+
+
+def test_two_traced_runs_at_once_read_their_own_traces(tmp_path):
+    """Two processes trace their windows at the same time: each reads its
+    own trace (its traced window is its own first call) and its own
+    scopes."""
+    children = {w: start_child(AT_ONCE.format(barrier=str(tmp_path),
+                                              workload=w))
+                for w in ("serve.mix16", "gnm11.rnp")}
+    outs = {w: child_result(c, timeout=900) for w, c in children.items()}
+    for out in outs.values():
+        assert out["correct"]
+        assert out["window_s"] == pytest.approx(out["call_s"], rel=0.02,
+                                                abs=0.01)
+    assert {"pack_ms_per_inst.serve", "verify_ms_per_inst.serve"} <= set(
+        outs["serve.mix16"]["metrics"])
+    assert "peel_ms.rnp" in outs["gnm11.rnp"]["metrics"]

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import glob
 import os
-import shutil
 from contextlib import contextmanager
 from typing import Dict, List, Tuple
 
@@ -43,10 +42,11 @@ def is_container(name: str) -> bool:
 
 @contextmanager
 def capture(out_dir: str):
+    """Profile the body into ``out_dir``, which is the run's own: nothing
+    in it is removed."""
     import jax
 
-    shutil.rmtree(out_dir, ignore_errors=True)
-    os.makedirs(out_dir)
+    os.makedirs(out_dir, exist_ok=True)
     jax.profiler.start_trace(out_dir)
     try:
         yield
